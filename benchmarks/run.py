@@ -195,7 +195,9 @@ def main(argv: list[str] | None = None) -> None:
 
     import benchmarks
     from benchmarks import common
+    from repro import compile_cache
 
+    compile_cache.enable()
     print("name,us_per_call,derived")
     t0 = time.perf_counter()
     per_module: dict[str, dict] = {}

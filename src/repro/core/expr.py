@@ -452,21 +452,21 @@ def leading_pair_mass(
     a, b, ok = _pair_kinematics(data, coll_a, coll_b,
                                 ("pt", "eta", "phi", "mass"))
 
-    def p4(c):
-        px = c["pt"] * np.cos(c["phi"])
-        py = c["pt"] * np.sin(c["phi"])
-        pz = c["pt"] * np.sinh(c["eta"])
-        ch = np.cosh(c["eta"])
-        e = np.sqrt(c["mass"] * c["mass"] + c["pt"] * c["pt"] * ch * ch)
-        return px, py, pz, e
+    def kin(c):
+        pt = c["pt"]
+        pz = pt * (0.5 * (np.exp(c["eta"]) - np.exp(-c["eta"])))
+        mtsq = c["mass"] * c["mass"] + pt * pt
+        return pt * np.cos(c["phi"]), pt * np.sin(c["phi"]), pz, mtsq, np.sqrt(mtsq + pz * pz)
 
-    pxa, pya, pza, ea = p4(a)
-    pxb, pyb, pzb, eb = p4(b)
+    px1, py1, pz1, mtsq1, e1 = kin(a)
+    px2, py2, pz2, mtsq2, e2 = kin(b)
+    ee, zz = e1 * e2, pz1 * pz2
+    den = ee + zz
+    boosted = (mtsq1 * mtsq2 + mtsq1 * pz2 * pz2 + mtsq2 * pz1 * pz1) / np.where(den > 0, den, 1.0)
+    long_ = np.where(zz > 0, boosted, ee - zz)
     m2 = (
-        (ea + eb) * (ea + eb)
-        - (pxa + pxb) * (pxa + pxb)
-        - (pya + pyb) * (pya + pyb)
-        - (pza + pzb) * (pza + pzb)
+        a["mass"] * a["mass"] + b["mass"] * b["mass"]
+        + 2.0 * (long_ - (px1 * px2 + py1 * py2))
     )
     return np.sqrt(np.maximum(m2, 0.0)), ok
 

@@ -325,6 +325,12 @@ def window_pad_K(data: dict[str, np.ndarray], program: Program, store) -> int:
 _WINDOW_QUANTUM = 512  # event-axis padding multiple (fused kernel tile)
 
 
+def default_fused_backend() -> str:
+    """The fused evaluator a run uses when none is forced: the Pallas
+    kernels on TPU, the compiled-program host interpreter elsewhere."""
+    return "pallas" if jax.default_backend() == "tpu" else "host"
+
+
 def fused_window_skim(
     data: dict[str, np.ndarray],
     program: Program,
@@ -378,12 +384,10 @@ def fused_window_skim(
         mask = np.ones(E, dtype=bool)
         return mask, {n: np.asarray(data[n]) for n in payload_branches}
 
-    import jax
-
     from repro.kernels import ops
 
     if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "host"
+        backend = default_fused_backend()
 
     if backend == "host":
         mask = program_eval_np(data, program, E)
@@ -413,8 +417,9 @@ def fused_window_skim(
     packed, count = ops.fused_skim(
         terms, valid, weights, payload, program, use_pallas=(backend == "pallas")
     )
-    k = int(count)
-    packed = np.asarray(packed[:k])
+    # slice on the host: a device slice per survivor count would compile
+    # a new program for every distinct count
+    packed = np.asarray(packed)[: int(count)]
     idx = packed[:, 0].astype(np.int64)
     real = idx < E  # drop phantom survivors from event-axis padding
     packed, idx = packed[real], idx[real]
@@ -438,8 +443,6 @@ def sharded_skim(mesh, program: Program, data_axes=("pod", "data")):
     scalar count are exposed to cross-shard collectives, which is the
     paper's "return only the filtered data" on the mesh.
     """
-    from jax.experimental.shard_map import shard_map
-
     axes = tuple(a for a in data_axes if a in mesh.axis_names)
 
     def _local(terms, valid, weights, payload):
@@ -452,12 +455,12 @@ def sharded_skim(mesh, program: Program, data_axes=("pod", "data")):
     spec_pay = P(axes, None)  # (E, D)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             _local,
             mesh=mesh,
             in_specs=(spec_e1, spec_e1, spec_e1, spec_pay),
             out_specs=(spec_pay, P(axes), P()),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -471,6 +474,7 @@ __all__ = [
     "compact_jnp",
     "program_eval_np",
     "fused_window_skim",
+    "default_fused_backend",
     "window_pad_K",
     "sharded_skim",
 ]

@@ -681,16 +681,11 @@ class CascadeExecutor:
         if not stage.branches:
             # constant sub-program (trigger OR over absent-era branches)
             return program_eval_np({}, stage.program, n)
-        if self._backend is None:
-            import jax
-
-            self._backend = (
-                "pallas" if jax.default_backend() == "tpu" else "host"
-            )
-        if self._backend == "host":
+        backend = self._resolve_backend()
+        if backend == "host":
             return program_eval_np(data, stage.program, n)
         mask, _ = fused_window_skim(
-            data, stage.program, self.store, backend=self._backend
+            data, stage.program, self.store, backend=backend
         )
         return mask
 
@@ -781,11 +776,9 @@ class CascadeExecutor:
 
     def _resolve_backend(self) -> str:
         if self._backend is None:
-            import jax
+            from repro.core.neardata import default_fused_backend
 
-            self._backend = (
-                "pallas" if jax.default_backend() == "tpu" else "host"
-            )
+            self._backend = default_fused_backend()
         return self._backend
 
     @staticmethod

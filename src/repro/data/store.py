@@ -304,9 +304,9 @@ class EventStore:
         # basket decode tier (DESIGN.md §16): "host" runs the numpy codec
         # reference, "device" ships compressed plane words to the kernel
         # tier (bitpack only; bit-identical by contract).  None resolves
-        # lazily — device iff a TPU backend is present, host otherwise —
-        # and any device failure falls back to host, counted in
-        # ``decode_fallbacks`` so the degradation is test-visible.
+        # lazily — device iff a TPU backend is present, host otherwise.
+        # Codecs with no device path decode on the host, counted in
+        # ``decode_fallbacks``; a device-tier failure raises.
         if decode_backend not in (None, "host", "device"):
             raise ValueError(f"unknown decode_backend {decode_backend!r}")
         self.decode_backend = decode_backend
@@ -676,38 +676,28 @@ class EventStore:
         if self._decode_backend_resolved is None:
             backend = self.decode_backend
             if backend is None:
-                try:
-                    import jax
+                import jax
 
-                    backend = (
-                        "device" if jax.default_backend() == "tpu" else "host"
-                    )
-                except Exception:
-                    backend = "host"
+                backend = "device" if jax.default_backend() == "tpu" else "host"
             self._decode_backend_resolved = backend
         return self._decode_backend_resolved
 
     def _decode_batch(self, name: str, blobs: list, dtype) -> list:
         """Backend-dispatched decode of one branch's blobs (no cache).
 
-        The device tier covers the bitpack codec only; other codecs (and
-        any device-path failure) fall back to the host reference, counted
-        in ``decode_fallbacks``.  Both tiers are bit-identical by the
-        codec contract (pinned in tests/test_device_batch.py)."""
+        The device tier covers the bitpack codec only; other codecs fall
+        back to the host reference, counted in ``decode_fallbacks``.  A
+        failure of the device tier itself propagates: a broken device
+        path must never pass as a host decode.  Both tiers are
+        bit-identical by the codec contract (pinned in
+        tests/test_device_batch.py)."""
         backend = self.resolved_decode_backend()
         if backend == "device" and blobs:
             if self.codec == "bitpack":
-                try:
-                    vals = decode_basket_batch(
-                        blobs, self.codec, dtype, backend="device"
-                    )
-                except Exception:
-                    with self._decode_lock:
-                        self.decode_fallbacks += len(blobs)
-                else:
-                    with self._decode_lock:
-                        self.decode_device_baskets += len(blobs)
-                    return vals
+                vals = decode_basket_batch(blobs, self.codec, dtype, backend="device")
+                with self._decode_lock:
+                    self.decode_device_baskets += len(blobs)
+                return vals
             else:
                 with self._decode_lock:
                     self.decode_fallbacks += len(blobs)

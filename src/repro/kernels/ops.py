@@ -159,7 +159,10 @@ def basket_decode_batch(parts_list, out_dtype, interpret=None, use_pallas=None):
     assert all(p["kind"] == kind for p in parts_list)
     if kind == 3:  # KIND_RAW_F32: literals — passthrough, nothing to decode
         return [p["raw"].astype(np.dtype(out_dtype)) for p in parts_list]
-    bits_max = max(p["bits"] for p in parts_list)
+    # plane count in buckets of 8 (extra planes are zero, so exact): four
+    # compiled decode programs per kind instead of one per bit width, and
+    # no zero-plane block for constant baskets
+    bits_max = min(32, max(1, -(-max(p["bits"] for p in parts_list) // 8)) * 8)
     wpp = [p["n_pad"] // 32 for p in parts_list]
     w_max = max(wpp)
     # lane-align word count (128-lane VPU)
@@ -201,7 +204,8 @@ def basket_decode_batch(parts_list, out_dtype, interpret=None, use_pallas=None):
 
 
 def _cascade_stage_impl(
-    terms, valid, weights, packed, seg_ids, *, program, nb, use_pallas
+    terms, valid, weights, packed, seg_ids, *, program, nb, use_pallas,
+    interpret=False,
 ):
     """One batched cascade stage, entirely on device.
 
@@ -222,7 +226,7 @@ def _cascade_stage_impl(
     Bn, T, E, K = terms.shape
     if use_pallas:
         m = _pe.predicate_eval_batch(
-            terms, valid, weights, program=program, interpret=False
+            terms, valid, weights, program=program, interpret=interpret
         )
     else:
         m = jax.vmap(
@@ -239,15 +243,12 @@ def _cascade_stage_impl(
     return new_packed, basket_alive, counts
 
 
-_cascade_stage_jit = jax.jit(
-    _cascade_stage_impl, static_argnames=("program", "nb", "use_pallas")
-)
+_CASCADE_STATIC = ("program", "nb", "use_pallas", "interpret")
+_cascade_stage_jit = jax.jit(_cascade_stage_impl, static_argnames=_CASCADE_STATIC)
 # accelerator variant: the carried mask buffer is donated — stage k+1
 # reuses stage k's words in place, so the masks never re-materialize
 _cascade_stage_jit_donated = jax.jit(
-    _cascade_stage_impl,
-    static_argnames=("program", "nb", "use_pallas"),
-    donate_argnums=(3,),
+    _cascade_stage_impl, static_argnames=_CASCADE_STATIC, donate_argnums=(3,)
 )
 
 
@@ -292,6 +293,7 @@ def warm_cascade_stage(
         program=program,
         nb=nb,
         use_pallas=use_pallas,
+        interpret=default_interpret(),
     )
     jax.block_until_ready(out)
     _note_dispatch(sig, warm=True)
@@ -325,6 +327,7 @@ def cascade_stage_step(
         program=program,
         nb=nb,
         use_pallas=use_pallas,
+        interpret=default_interpret(),
     )
 
 
@@ -370,9 +373,7 @@ def fused_skim_batch(
             terms, valid, weights, payload, program=program,
             interpret=default_interpret(), event_tile=tile,
         )
-        out = jax.vmap(
-            functools.partial(_sf.stitch_tiles, event_tile=tile)
-        )(tiles, counts)
+        out = jax.vmap(_sf.stitch_tiles)(tiles, counts)
         return out, counts.sum(axis=1)
     return _fused_ref_batch(terms, valid, weights, payload, program=program)
 
@@ -396,7 +397,7 @@ def skim_fused(terms, valid, weights, payload, program: Program, interpret=None)
         interpret=interpret, event_tile=tile,
     )
     # stitch tiles at global offsets (same epilogue as stream_compact)
-    out = _sf.stitch_tiles(packed_tiles, counts, event_tile=tile)
+    out = _sf.stitch_tiles(packed_tiles, counts)
     return out[:E], counts.sum()
 
 

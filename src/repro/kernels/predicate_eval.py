@@ -19,6 +19,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.expr import KINEMATIC_VARS, RPN_BRANCH, RPN_SUM
 from repro.kernels.ref import (
@@ -33,6 +34,25 @@ from repro.kernels.ref import (
 )
 
 EVENT_TILE = 1024  # events per grid step; multiple of 8*128 lanes
+
+# VMEM sizing.  Object slots K sit on the 128-wide lane axis, so a block
+# row of K <= 128 floats occupies a full 512-byte lane row; the input
+# blocks of one grid step (T term planes + G validity + G weight planes,
+# double-buffered) are held under _BLOCK_BUDGET by shrinking the event
+# tile, and the scoped limit leaves room for the body's (tile, K)
+# temporaries (the mass group keeps a few dozen alive).
+_BLOCK_BUDGET = 4 << 20
+_MIN_TILE = 128  # mask blocks must stay a multiple of 128 lanes
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 << 20)
+
+
+def fit_event_tile(event_tile: int, planes: int, K: int) -> int:
+    """Largest tile <= ``event_tile`` (halving) whose double-buffered
+    input blocks of ``planes`` (tile, K) float planes fit the budget."""
+    row_bytes = 4 * 128 * -(-K // 128)
+    while event_tile > _MIN_TILE and 2 * planes * event_tile * row_bytes > _BLOCK_BUDGET:
+        event_tile //= 2
+    return event_tile
 
 
 @dataclass(frozen=True)
@@ -233,7 +253,7 @@ def _predicate_kernel(terms_ref, valid_ref, weights_ref, out_ref, *, program: Pr
     mask = predicate_mask(
         program, terms_ref[...], valid_ref[...], weights_ref[...]
     )
-    out_ref[...] = mask.astype(jnp.int32)
+    out_ref[...] = mask.astype(jnp.int32).reshape(out_ref.shape)
 
 
 def _predicate_kernel_batched(terms_ref, valid_ref, weights_ref, out_ref,
@@ -242,7 +262,7 @@ def _predicate_kernel_batched(terms_ref, valid_ref, weights_ref, out_ref,
     mask = predicate_mask(
         program, terms_ref[0], valid_ref[0], weights_ref[0]
     )
-    out_ref[0] = mask.astype(jnp.int32)
+    out_ref[...] = mask.astype(jnp.int32).reshape(out_ref.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("program", "interpret", "event_tile"))
@@ -264,10 +284,13 @@ def predicate_eval_batch(
     """
     Bn, T, E, K = terms.shape
     G = valid.shape[1]
+    event_tile = fit_event_tile(event_tile, T + 2 * G, K)
     assert E % event_tile == 0, (E, event_tile)
     grid = (Bn, E // event_tile)
 
-    return pl.pallas_call(
+    # the mask leaves as (B, 1, E): a (1, 1, tile) block keeps the window
+    # row off the sublane axis, where a 1-row block breaks the (8, 128) rule
+    out = pl.pallas_call(
         functools.partial(_predicate_kernel_batched, program=program),
         grid=grid,
         in_specs=[
@@ -275,10 +298,12 @@ def predicate_eval_batch(
             pl.BlockSpec((1, G, event_tile, K), lambda b, i: (b, 0, i, 0)),
             pl.BlockSpec((1, G, event_tile, K), lambda b, i: (b, 0, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, event_tile), lambda b, i: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((Bn, E), jnp.int32),
+        out_specs=pl.BlockSpec((1, 1, event_tile), lambda b, i: (b, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((Bn, 1, E), jnp.int32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(terms, valid, weights)
+    return out.reshape(Bn, E)
 
 
 @functools.partial(jax.jit, static_argnames=("program", "interpret", "event_tile"))
@@ -294,14 +319,17 @@ def predicate_eval(
     """Evaluate the predicate program; returns (E,) int32 survivor mask.
 
     ``terms`` (T, E, K) float32, ``valid``/``weights`` (G, E, K).  ``E``
-    must be a multiple of ``event_tile`` (the ingest path pads).
+    must be a multiple of ``event_tile`` (the ingest path pads), which is
+    an upper bound: wide programs run a smaller tile (:func:`fit_event_tile`).
     """
     T, E, K = terms.shape
     G = valid.shape[0]
+    event_tile = fit_event_tile(event_tile, T + 2 * G, K)
     assert E % event_tile == 0, (E, event_tile)
     grid = (E // event_tile,)
 
-    return pl.pallas_call(
+    # (1, E) out: a rank-1 block would have to match XLA's 1024-lane tiling
+    out = pl.pallas_call(
         functools.partial(_predicate_kernel, program=program),
         grid=grid,
         in_specs=[
@@ -309,7 +337,9 @@ def predicate_eval(
             pl.BlockSpec((G, event_tile, K), lambda i: (0, i, 0)),
             pl.BlockSpec((G, event_tile, K), lambda i: (0, i, 0)),
         ],
-        out_specs=pl.BlockSpec((event_tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((E,), jnp.int32),
+        out_specs=pl.BlockSpec((1, event_tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, E), jnp.int32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(terms, valid, weights)
+    return out.reshape(E)

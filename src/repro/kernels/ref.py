@@ -107,30 +107,41 @@ def _unpack_validity(vg: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     return jnp.mod(vg, 2.0) >= 1.0, vg >= 2.0
 
 
-def _p4(pt, eta, phi, mass):
-    """(px, py, pz, E) from detector coordinates — mirrored term-for-term
-    by the float64 host helper (repro.core.expr.leading_pair_mass)."""
-    px = pt * jnp.cos(phi)
-    py = pt * jnp.sin(phi)
-    pz = pt * jnp.sinh(eta)
-    ch = jnp.cosh(eta)
-    e = jnp.sqrt(mass * mass + pt * pt * ch * ch)
-    return px, py, pz, e
+def _pair_mass(a, b):
+    """Invariant mass of two objects given as (pt, eta, phi, mass) —
+    mirrored term-for-term by the float64 host helper
+    (repro.core.expr.leading_pair_mass).
+
+    Written without catastrophic cancellation, so the float32 result
+    stays within a few ulp of float64:
+    m² = m1² + m2² + 2(E1E2 − pz1pz2 − pT1·pT2), with E1E2 − pz1pz2
+    taken as (mT1²mT2² + mT1²pz2² + mT2²pz1²) / (E1E2 + pz1pz2) when
+    pz1pz2 > 0 (two boosted objects, E ≈ |pz|).  The textbook
+    (E1+E2)² − |p1+p2|² loses ~4 digits there.  sinh is spelled
+    through ``exp``, which the TPU kernel compiler lowers."""
+    def kin(pt, eta, phi, mass):
+        pz = pt * (0.5 * (jnp.exp(eta) - jnp.exp(-eta)))
+        mtsq = mass * mass + pt * pt
+        return pt * jnp.cos(phi), pt * jnp.sin(phi), pz, mtsq, jnp.sqrt(mtsq + pz * pz)
+
+    px1, py1, pz1, mtsq1, e1 = kin(*a)
+    px2, py2, pz2, mtsq2, e2 = kin(*b)
+    ee, zz = e1 * e2, pz1 * pz2
+    den = ee + zz
+    boosted = (mtsq1 * mtsq2 + mtsq1 * pz2 * pz2 + mtsq2 * pz1 * pz1) / jnp.where(den > 0, den, 1.0)
+    long_ = jnp.where(zz > 0, boosted, ee - zz)
+    m2 = a[3] * a[3] + b[3] * b[3] + 2.0 * (long_ - (px1 * px2 + py1 * py2))
+    return jnp.sqrt(jnp.maximum(m2, 0.0))
 
 
 def _group_mass(grp, terms, vg, same: bool):
     ids = grp.term_ids  # (ptA, etaA, phiA, massA, ptB, etaB, phiB, massB)
     va, vb = _unpack_validity(vg)
     oh1, oh2, ok = _pair_onehots(terms[ids[0]], va, terms[ids[4]], vb, same)
-    px1, py1, pz1, e1 = _p4(*(_sel(terms[i], oh1) for i in ids[:4]))
-    px2, py2, pz2, e2 = _p4(*(_sel(terms[i], oh2) for i in ids[4:]))
-    m2 = (
-        (e1 + e2) * (e1 + e2)
-        - (px1 + px2) * (px1 + px2)
-        - (py1 + py2) * (py1 + py2)
-        - (pz1 + pz2) * (pz1 + pz2)
+    mass = _pair_mass(
+        [_sel(terms[i], oh1) for i in ids[:4]],
+        [_sel(terms[i], oh2) for i in ids[4:]],
     )
-    mass = jnp.sqrt(jnp.maximum(m2, 0.0))
     return ok & (mass >= grp.cmp_thr) & (mass <= grp.cmp_thr2)
 
 

@@ -19,25 +19,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.skim_fused import compact_tile, stitch_tiles
 
 EVENT_TILE = 512  # rows per tile; one-hot matmul is (512, 512) x (512, D)
 
 
 def _compact_kernel(payload_ref, mask_ref, out_ref, count_ref):
-    Eb = payload_ref.shape[0]
-    mask = mask_ref[...] > 0  # (Eb,)
-    maskf = mask.astype(jnp.float32)
-    # exclusive prefix sum -> destination row for each surviving row
-    pos = jnp.cumsum(maskf) - maskf  # (Eb,) float32, integral values
-    rows = jax.lax.broadcasted_iota(jnp.float32, (Eb, Eb), 0)  # dest index j
-    # one-hot permutation: P[j, i] = 1 iff row i survives and lands at j
-    onehot = (rows == pos[None, :]) & mask[None, :]
-    out_ref[...] = jnp.dot(
-        onehot.astype(jnp.float32),
-        payload_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    ).astype(out_ref.dtype)
-    count_ref[0] = mask.astype(jnp.int32).sum()
+    packed, count = compact_tile(mask_ref[0] > 0, payload_ref[...])
+    out_ref[...] = packed.astype(out_ref.dtype)
+    count_ref[pl.program_id(0)] = count
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "event_tile"))
@@ -60,31 +52,18 @@ def stream_compact(
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((event_tile, D), lambda i: (i, 0)),
-            pl.BlockSpec((event_tile,), lambda i: (i,)),
+            pl.BlockSpec((1, event_tile), lambda i: (0, i)),
         ],
         out_specs=[
             pl.BlockSpec((event_tile, D), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((E, D), payload.dtype),
             jax.ShapeDtypeStruct((n_tiles,), jnp.int32),
         ],
         interpret=interpret,
-    )(payload, mask.astype(jnp.int32))
+    )(payload, mask.astype(jnp.int32).reshape(1, E))
 
-    # pass 2: stitch tiles at global offsets (host-side jnp scan)
-    tiles = packed_tiles.reshape(n_tiles, event_tile, D)
-    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(counts)[:-1]])
-
-    def place(acc, inp):
-        # rows beyond each tile's survivor count are zero, and tiles write
-        # to disjoint [off, off+count) ranges — accumulate-add is exact.
-        tile, off = inp
-        cur = jax.lax.dynamic_slice(acc, (off, 0), (event_tile, D))
-        acc = jax.lax.dynamic_update_slice(acc, cur + tile, (off, 0))
-        return acc, None
-
-    out0 = jnp.zeros((E + event_tile, D), payload.dtype)
-    out, _ = jax.lax.scan(place, out0, (tiles, offsets))
-    return out[:E], counts.sum()
+    # pass 2: stitch tiles at global offsets (jnp scan)
+    return stitch_tiles(packed_tiles, counts), counts.sum()

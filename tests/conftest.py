@@ -1,7 +1,7 @@
 import os
 import sys
 
-# tests run single-device CPU; dry-run owns the 512-device flag
+# tests run on the CPU, Pallas kernels in interpret mode
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 # tier-1 runs with the static verifier on: every compile_query/plan_skim

@@ -352,6 +352,23 @@ def test_non_bitpack_device_request_falls_back_visibly():
     assert stats["device_baskets"] == 0
 
 
+def test_device_decode_failure_raises_not_falls_back(monkeypatch):
+    """A broken device tier on a codec that has one is an error, never a
+    quiet host decode."""
+    from repro.data import store as store_mod
+
+    def broken(*_a, **_kw):
+        raise RuntimeError("device decode unavailable")
+
+    st = _tiny_store("bitpack", decode_backend="device")
+    monkeypatch.setattr(store_mod, "decode_basket_batch", broken)
+    with pytest.raises(RuntimeError, match="device decode unavailable"):
+        st.read_flat("MET_pt")
+    stats = st.decode_backend_stats()
+    assert stats["fallbacks"] == 0
+    assert stats["host_baskets"] == 0 and stats["device_baskets"] == 0
+
+
 def test_invalid_decode_backend_rejected():
     with pytest.raises(ValueError):
         _tiny_store("bitpack", decode_backend="gpu")
@@ -370,3 +387,14 @@ def test_batched_run_with_device_decode_bit_identical(reference):
     _assert_same_output(res, reference)
     assert res.extras["decode_backend"] == "device"
     assert st.decode_backend_stats()["device_baskets"] > 0
+
+
+@pytest.mark.parametrize("device_batch", [None, 3])
+def test_pallas_backend_bit_identical_in_interpret_mode(store, reference, device_batch):
+    """The Pallas fused / batched-cascade kernels (interpret mode on this
+    backend) through the engine, per window and window-batched."""
+    res = run_skim(
+        store, QUERY, mode="near_data", pipeline=False, prune=False,
+        cascade=True, device_batch=device_batch, fused_backend="pallas",
+    )
+    _assert_same_output(res, reference)

@@ -106,11 +106,13 @@ def test_stream_compact_preserves_order():
     ],
 )
 @pytest.mark.parametrize("sizes", [(64,), (100, 5000, 333), (4096, 4096)])
-def test_basket_decode_sweep(dtype, gen, sizes):
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_basket_decode_sweep(dtype, gen, sizes, use_pallas):
+    """Jitted jnp mirror and the Pallas kernel (interpret mode here)."""
     arrs = [gen(n) for n in sizes]
     parts = [bitpack_raw_parts(bitpack_encode(a)) for a in arrs]
     out_dtype = jnp.int32 if dtype == np.int32 else jnp.float32
-    outs = ops.basket_decode_batch(parts, out_dtype)
+    outs = ops.basket_decode_batch(parts, out_dtype, use_pallas=use_pallas)
     for a, o in zip(arrs, outs):
         np.testing.assert_array_equal(np.asarray(o), a.astype(np.asarray(o).dtype))
 

@@ -65,3 +65,18 @@ def test_fused_empty_and_full():
     packed, count = ops.skim_fused(terms, valid, weights, payload, prog)
     assert int(count) == 0
     assert np.all(np.asarray(packed) == 0)
+
+
+def test_fused_batch_pallas_matches_oracle():
+    """The window-batched Pallas kernel (interpret mode here) against the
+    vmapped jnp oracle, window by window."""
+    prog = _program()
+    B, E, K, D = 3, 1024, 4, 3
+    terms = RNG.normal(20, 15, (B, 4, E, K)).astype(np.float32)
+    valid = (RNG.random((B, 3, E, K)) < 0.4).astype(np.float32)
+    weights = np.abs(RNG.normal(30, 20, (B, 3, E, K))).astype(np.float32)
+    payload = RNG.normal(size=(B, E, D)).astype(np.float32)
+    got, got_n = ops.fused_skim_batch(terms, valid, weights, payload, prog, use_pallas=True)
+    want, want_n = ops.fused_skim_batch(terms, valid, weights, payload, prog, use_pallas=False)
+    np.testing.assert_array_equal(np.asarray(got_n), np.asarray(want_n))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
