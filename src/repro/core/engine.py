@@ -235,7 +235,9 @@ def _decode_branches(
         blobs = window[name]
         parts = []
         with _Timer(breakdown, "decompress"):
-            decoded = store.decode_blobs(name, [blob for _, blob in blobs])
+            decoded = store.decode_blobs(
+                name, [blob for _, blob in blobs], tracer=tr
+            )
         with _Timer(breakdown, "deserialize"):
             br = store.branches[name]
             for (meta, _), vals in zip(blobs, decoded):
@@ -729,7 +731,9 @@ class SkimEngine:
             # span stack; its loads go untraced in "threads" mode (the
             # serial schedules trace them as load_window spans)
             ltr = NULL_TRACER if use_threads else tracer
-            lsid = ltr.begin("load_window", kind="fetch", window=start // chunk)
+            lsid = ltr.begin(
+                "load_window", kind="load_window", window=start // chunk
+            )
             data = _decode_branches(
                 store, names, start, stop, lb, ls, coalesce, tracer=ltr
             )
@@ -901,6 +905,7 @@ class SkimEngine:
                             K=pad_K,
                             pad_to=chunk,
                             backend=self.fused_backend,
+                            tracer=tracer,
                         )
                     tracer.end(ksid)
             else:
@@ -937,7 +942,7 @@ class SkimEngine:
             part_jagged: dict = {}
             if k:
                 n_passed += k
-                p2sid = tracer.begin("phase2", kind="fetch", window=wi)
+                p2sid = tracer.begin("phase2", kind="phase2", window=wi)
                 if outcome is not None:
                     # ---- phase 2 (cascaded window): the basket ledger
                     # dedups against phase 1, so filter∩output branches a

@@ -24,6 +24,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import expr as xpr
 from repro.kernels import ref as kref
 from repro.kernels.predicate_eval import Program, compile_query
+from repro.obs.trace import NULL_TRACER
 
 
 @dataclass
@@ -340,6 +341,7 @@ def fused_window_skim(
     pad_to: int | None = None,
     backend: str | None = None,
     decision: str = "scan",
+    tracer=None,
 ) -> tuple[np.ndarray, dict[str, np.ndarray]]:
     """One-pass skim of a decoded window (the engine's fused path).
 
@@ -374,6 +376,11 @@ def fused_window_skim(
     to ``arr[all-true mask]``).  ``"scan"`` (default) runs the normal
     fused evaluation.  Pruned windows never reach this function: their
     data is never fetched, let alone decoded.
+
+    ``tracer`` records the device backends' host–device boundary: the
+    densification (``stage_inputs``), the uploads and kernel enqueue
+    (``device_launch``) and the one blocking read-back of the compacted
+    rows and their count (``device_wait``).
     """
     flat = next(
         n for n in data if not (store.branches.get(n) and store.branches[n].jagged)
@@ -398,28 +405,48 @@ def fused_window_skim(
     if backend not in ("pallas", "xla"):
         raise ValueError(f"unknown fused backend {backend!r}")
 
+    tr = tracer if tracer is not None else NULL_TRACER
     if K is None:
         K = window_pad_K(data, program, store)
-    pb = build_padded_inputs(
-        data, program, store, K=K,
-        payload_branches=list(payload_branches), include_index=True,
-    )
+    with tr.span("stage_inputs", kind="stage_inputs") as sp:
+        pb = build_padded_inputs(
+            data, program, store, K=K,
+            payload_branches=list(payload_branches), include_index=True,
+            to_device=False,
+        )
+        if tr.enabled:
+            sp["events"] = E
+            sp["K"] = K
     target = -(-max(E, pad_to or E) // _WINDOW_QUANTUM) * _WINDOW_QUANTUM
-    terms, valid, weights, payload = pb.terms, pb.valid, pb.weights, pb.payload
-    if target > E:
-        pad = target - E
-        terms = jnp.pad(terms, ((0, 0), (0, pad), (0, 0)))
-        valid = jnp.pad(valid, ((0, 0), (0, pad), (0, 0)))
-        weights = jnp.pad(weights, ((0, 0), (0, pad), (0, 0)))
-        payload = jnp.pad(payload, ((0, pad), (0, 0)))
-        payload = payload.at[E:, 0].set(jnp.arange(E, target, dtype=jnp.float32))
-
-    packed, count = ops.fused_skim(
-        terms, valid, weights, payload, program, use_pallas=(backend == "pallas")
-    )
-    # slice on the host: a device slice per survivor count would compile
-    # a new program for every distinct count
-    packed = np.asarray(packed)[: int(count)]
+    host = (pb.terms, pb.valid, pb.weights, pb.payload)
+    with tr.span("device_launch", kind="device_launch") as sp:
+        terms, valid, weights, payload = (jnp.asarray(a) for a in host)
+        if target > E:
+            pad = target - E
+            terms = jnp.pad(terms, ((0, 0), (0, pad), (0, 0)))
+            valid = jnp.pad(valid, ((0, 0), (0, pad), (0, 0)))
+            weights = jnp.pad(weights, ((0, 0), (0, pad), (0, 0)))
+            payload = jnp.pad(payload, ((0, pad), (0, 0)))
+            payload = payload.at[E:, 0].set(
+                jnp.arange(E, target, dtype=jnp.float32)
+            )
+        packed, count = ops.fused_skim(
+            terms, valid, weights, payload, program,
+            use_pallas=(backend == "pallas"),
+        )
+        if tr.enabled:
+            sp["op"] = "fused_skim"
+            sp["h2d_bytes"] = sum(a.nbytes for a in host)
+    with tr.span("device_wait", kind="device_wait") as sp:
+        # slice on the host: a device slice per survivor count would
+        # compile a new program for every distinct count
+        rows = np.asarray(packed)
+        n = int(count)
+        if tr.enabled:
+            sp["op"] = "fused_skim"
+            sp["d2h_bytes"] = rows.nbytes + count.nbytes
+            sp["arrays"] = 2
+    packed = rows[:n]
     idx = packed[:, 0].astype(np.int64)
     real = idx < E  # drop phantom survivors from event-axis padding
     packed, idx = packed[real], idx[real]

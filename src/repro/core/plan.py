@@ -685,7 +685,8 @@ class CascadeExecutor:
         if backend == "host":
             return program_eval_np(data, stage.program, n)
         mask, _ = fused_window_skim(
-            data, stage.program, self.store, backend=backend
+            data, stage.program, self.store, backend=backend,
+            tracer=self.tracer,
         )
         return mask
 
@@ -857,28 +858,37 @@ class CascadeExecutor:
         nb = pad_E // be + 2
         use_pallas = self._resolve_backend() == "pallas"
 
-        # initial masks: real events alive, batch/event padding dead —
-        # phantom events can never surface in a survivor set
-        init = np.zeros((Bn, pad_E), dtype=bool)
-        seg = np.zeros((Bn, pad_E), dtype=np.int32)
-        for b, (start, stop, *_r) in enumerate(entries):
-            init[b, : stop - start] = True
-            grid0 = start - start % be
-            ids = (start + np.arange(pad_E, dtype=np.int64) - grid0) // be
-            seg[b] = np.clip(ids, 0, nb - 1).astype(np.int32)
-        packed = jnp.asarray(ops.pack_mask(init))
-        seg_ids = jnp.asarray(seg)
-        maybe_verify_device_batch(
-            [(s, t) for (s, t, *_r) in entries],
-            pad_E, Bn, nb, be, int(packed.shape[1]),
-        )
-
+        tr = self.tracer
         order = self.order()  # frozen for the batch (any order is
         # bit-identical on survivors; the adaptive re-rank applies
         # between batches, exactly as it applies between windows)
-        bsid = self.tracer.begin(
+        bsid = tr.begin(
             "device_batch", kind="device_batch",
             windows=B_real, pad_windows=Bn, pad_events=pad_E,
+        )
+
+        # initial masks: real events alive, batch/event padding dead —
+        # phantom events can never surface in a survivor set
+        with tr.span("stage_inputs", kind="stage_inputs") as sp:
+            init = np.zeros((Bn, pad_E), dtype=bool)
+            seg = np.zeros((Bn, pad_E), dtype=np.int32)
+            for b, (start, stop, *_r) in enumerate(entries):
+                init[b, : stop - start] = True
+                grid0 = start - start % be
+                ids = (start + np.arange(pad_E, dtype=np.int64) - grid0) // be
+                seg[b] = np.clip(ids, 0, nb - 1).astype(np.int32)
+            words0 = ops.pack_mask(init)
+            if tr.enabled:
+                sp["events"] = Bn * pad_E
+        with tr.span("device_launch", kind="device_launch") as sp:
+            packed = jnp.asarray(words0)
+            seg_ids = jnp.asarray(seg)
+            if tr.enabled:
+                sp["op"] = "cascade_stage"
+                sp["h2d_bytes"] = words0.nbytes + seg.nbytes
+        maybe_verify_device_batch(
+            [(s, t) for (s, t, *_r) in entries],
+            pad_E, Bn, nb, be, int(packed.shape[1]),
         )
 
         counts_host = np.array(sizes + [0] * (Bn - B_real), dtype=np.int64)
@@ -897,7 +907,7 @@ class CascadeExecutor:
                 continue  # whole batch dead: no staging, no dispatch
             for b in alive:
                 stages_run[b] += 1
-            ssid = self.tracer.begin(
+            ssid = tr.begin(
                 f"stage[{si}]", kind="cascade_stage", stage=si,
                 node=stage_kind(stage), tier=stage.tier, batch=len(alive),
             )
@@ -932,7 +942,7 @@ class CascadeExecutor:
                         )
                         sdata = _decode_branches(
                             store, list(stage.branches), a, z, breakdown,
-                            FetchStats(), self.coalesce, tracer=self.tracer,
+                            FetchStats(), self.coalesce, tracer=tr,
                         )
                     staged[b].append((a - start, z - a, sdata))
                     if z - a == stop - start:
@@ -945,17 +955,21 @@ class CascadeExecutor:
 
             # -- stage the batch tensors (zeros outside alive spans) -----
             T, G = stage.program.n_terms, stage.program.n_groups
-            terms = np.zeros((Bn, T, pad_E, K_b), np.float32)
-            valid = np.zeros((Bn, G, pad_E, K_b), np.float32)
-            weights = np.zeros((Bn, G, pad_E, K_b), np.float32)
-            for b in alive:
-                for off, n, sdata in staged[b]:
-                    pb = nd.build_padded_inputs(
-                        sdata, stage.program, store, K=K_b, to_device=False
-                    )
-                    terms[b, :, off : off + n, :] = pb.terms
-                    valid[b, :, off : off + n, :] = pb.valid
-                    weights[b, :, off : off + n, :] = pb.weights
+            with tr.span("stage_inputs", kind="stage_inputs") as sp:
+                terms = np.zeros((Bn, T, pad_E, K_b), np.float32)
+                valid = np.zeros((Bn, G, pad_E, K_b), np.float32)
+                weights = np.zeros((Bn, G, pad_E, K_b), np.float32)
+                for b in alive:
+                    for off, n, sdata in staged[b]:
+                        pb = nd.build_padded_inputs(
+                            sdata, stage.program, store, K=K_b, to_device=False
+                        )
+                        terms[b, :, off : off + n, :] = pb.terms
+                        valid[b, :, off : off + n, :] = pb.valid
+                        weights[b, :, off : off + n, :] = pb.weights
+                if tr.enabled:
+                    sp["events"] = Bn * pad_E
+                    sp["K"] = K_b
 
             # warm the compiled step per shape bucket OUTSIDE the stage
             # timers: measured filter time is steady-state dispatch
@@ -965,12 +979,23 @@ class CascadeExecutor:
             )
 
             t0 = _time.perf_counter()
-            packed, basket_dev, counts_dev = ops.cascade_stage_step(
-                terms, valid, weights, packed, seg_ids,
-                stage.program, nb, use_pallas=use_pallas,
-            )
-            basket_bits = np.asarray(basket_dev).astype(bool)
-            counts_new = np.asarray(counts_dev).astype(np.int64)
+            with tr.span("device_launch", kind="device_launch") as sp:
+                packed, basket_dev, counts_dev = ops.cascade_stage_step(
+                    terms, valid, weights, packed, seg_ids,
+                    stage.program, nb, use_pallas=use_pallas,
+                )
+                if tr.enabled:
+                    sp["op"] = "cascade_stage"
+                    sp["h2d_bytes"] = terms.nbytes + valid.nbytes + weights.nbytes
+            with tr.span("device_wait", kind="device_wait") as sp:
+                basket_host = np.asarray(basket_dev)
+                counts_host_new = np.asarray(counts_dev)
+                if tr.enabled:
+                    sp["op"] = "cascade_stage"
+                    sp["d2h_bytes"] = basket_host.nbytes + counts_host_new.nbytes
+                    sp["arrays"] = 2
+            basket_bits = basket_host.astype(bool)
+            counts_new = counts_host_new.astype(np.int64)
             elapsed = _time.perf_counter() - t0
             share = elapsed / len(alive)
 
@@ -985,13 +1010,18 @@ class CascadeExecutor:
                 batch_in += alive_in
                 batch_out += alive_out
             counts_host = counts_new
-            self.tracer.end(
+            tr.end(
                 ssid, alive_in=batch_in, alive_out=batch_out,
                 bytes=sum(stage_bytes),
             )
 
         # the one host round trip for event-level masks: batch boundary
-        words = np.asarray(packed)
+        with tr.span("device_wait", kind="device_wait") as sp:
+            words = np.asarray(packed)
+            if tr.enabled:
+                sp["op"] = "cascade_stage"
+                sp["d2h_bytes"] = words.nbytes
+                sp["arrays"] = 1
         outcomes = []
         for b, (start, stop, *_r) in enumerate(entries):
             mask = ops.unpack_mask(words[b], pad_E)[: stop - start].copy()
@@ -1003,7 +1033,7 @@ class CascadeExecutor:
                     stages_run=stages_run[b],
                 )
             )
-        self.tracer.end(bsid, stages=len(order))
+        tr.end(bsid, stages=len(order))
         return outcomes
 
     # -- phase 2 through the same ledger -------------------------------------

@@ -39,6 +39,8 @@ import zlib as _zlib
 
 import numpy as np
 
+from repro.obs.trace import NULL_TRACER
+
 _MAGIC = 0x534B4D52  # "SKMR"
 
 KIND_INT = 0
@@ -265,7 +267,7 @@ def decode_basket(blob: bytes, codec: str, dtype) -> np.ndarray:
 
 
 def decode_basket_batch(
-    blobs: list, codec: str, dtype, backend: str = "host"
+    blobs: list, codec: str, dtype, backend: str = "host", tracer=None
 ) -> list:
     """Decode a list of basket blobs in one round (DESIGN.md §16).
 
@@ -278,14 +280,19 @@ def decode_basket_batch(
     group is one dispatch.  Output order matches ``blobs`` and is
     bit-identical to the host reference for every kind (int zigzag-delta
     prefix sums are wrap-exact int32, float prefix-xor is exact, bools
-    and raw literals are identity).
+    and raw literals are identity).  ``tracer`` records the parsing as
+    ``decode_prep`` and is passed on to each device call.
     """
     if backend != "device" or codec != "bitpack":
         decode = CODECS[codec][1]
         return [decode(blob, dtype) for blob in blobs]
     from repro.kernels import ops
 
-    parts = [bitpack_raw_parts(blob) for blob in blobs]
+    tr = tracer if tracer is not None else NULL_TRACER
+    with tr.span("decode_prep", kind="decode_prep") as sp:
+        parts = [bitpack_raw_parts(blob) for blob in blobs]
+        if tr.enabled:
+            sp["baskets"] = len(blobs)
     out: list = [None] * len(blobs)
     groups: dict[int, list[int]] = {}
     for i, p in enumerate(parts):
@@ -294,7 +301,9 @@ def decode_basket_batch(
         else:
             groups.setdefault(p["kind"], []).append(i)
     for _kind, idxs in sorted(groups.items()):
-        decoded = ops.basket_decode_batch([parts[i] for i in idxs], dtype)
+        decoded = ops.basket_decode_batch(
+            [parts[i] for i in idxs], dtype, tracer=tr
+        )
         for i, vals in zip(idxs, decoded):
             out[i] = np.asarray(vals)
     return out

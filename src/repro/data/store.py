@@ -682,7 +682,7 @@ class EventStore:
             self._decode_backend_resolved = backend
         return self._decode_backend_resolved
 
-    def _decode_batch(self, name: str, blobs: list, dtype) -> list:
+    def _decode_batch(self, name: str, blobs: list, dtype, tracer=None) -> list:
         """Backend-dispatched decode of one branch's blobs (no cache).
 
         The device tier covers the bitpack codec only; other codecs fall
@@ -694,7 +694,9 @@ class EventStore:
         backend = self.resolved_decode_backend()
         if backend == "device" and blobs:
             if self.codec == "bitpack":
-                vals = decode_basket_batch(blobs, self.codec, dtype, backend="device")
+                vals = decode_basket_batch(
+                    blobs, self.codec, dtype, backend="device", tracer=tracer
+                )
                 with self._decode_lock:
                     self.decode_device_baskets += len(blobs)
                 return vals
@@ -717,17 +719,18 @@ class EventStore:
         """
         return self.decode_blobs(name, [blob])[0]
 
-    def decode_blobs(self, name: str, blobs: list) -> list:
+    def decode_blobs(self, name: str, blobs: list, tracer=None) -> list:
         """Decode a list of basket blobs for one branch in one round.
 
         The batch form of :meth:`decode_blob` (same LRU, same freezing):
         cache misses decode together through the backend-selected tier
         (:meth:`_decode_batch`), so a device-backed store pays one kernel
-        dispatch per fetch round instead of one per basket.
+        dispatch per fetch round instead of one per basket.  ``tracer``
+        receives the device tier's host–device spans.
         """
         dtype = self.branches[name].np_dtype()
         if self.decode_cache_baskets <= 0:
-            return self._decode_batch(name, list(blobs), dtype)
+            return self._decode_batch(name, list(blobs), dtype, tracer=tracer)
         out: list = [None] * len(blobs)
         misses: list[int] = []
         with self._decode_lock:
@@ -743,7 +746,7 @@ class EventStore:
                     misses.append(i)
         if misses:
             decoded = self._decode_batch(
-                name, [blobs[i] for i in misses], dtype
+                name, [blobs[i] for i in misses], dtype, tracer=tracer
             )
             with self._decode_lock:
                 for i, vals in zip(misses, decoded):

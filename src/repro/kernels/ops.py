@@ -18,6 +18,7 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import predicate_eval as _pe
 from repro.kernels import stream_compact as _sc
 from repro.kernels.predicate_eval import Program, compile_query  # re-export
+from repro.obs.trace import NULL_TRACER
 
 
 def default_interpret() -> bool:
@@ -143,7 +144,9 @@ def stream_compact(payload, mask, interpret=None):
     return packed[:E], count
 
 
-def basket_decode_batch(parts_list, out_dtype, interpret=None, use_pallas=None):
+def basket_decode_batch(
+    parts_list, out_dtype, interpret=None, use_pallas=None, tracer=None
+):
     """Decode a batch of ``bitpack_raw_parts`` dicts of the same kind.
 
     Pads plane counts/words to the batch max, runs the decode once on the
@@ -151,7 +154,12 @@ def basket_decode_batch(parts_list, out_dtype, interpret=None, use_pallas=None):
     (:func:`repro.kernels.basket_decode.basket_decode_ref`) elsewhere —
     and returns a list of correctly-sized arrays, bit-identical to the
     host codec reference (``repro.data.codecs.bitpack_decode``).
+
+    ``tracer`` records the host–device boundary of the call: the plane
+    padding (``decode_prep``), the upload and enqueue (``device_launch``)
+    and the blocking read-back (``device_wait``).
     """
+    tr = tracer if tracer is not None else NULL_TRACER
     interpret = default_interpret() if interpret is None else interpret
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
@@ -159,42 +167,46 @@ def basket_decode_batch(parts_list, out_dtype, interpret=None, use_pallas=None):
     assert all(p["kind"] == kind for p in parts_list)
     if kind == 3:  # KIND_RAW_F32: literals — passthrough, nothing to decode
         return [p["raw"].astype(np.dtype(out_dtype)) for p in parts_list]
-    # plane count in buckets of 8 (extra planes are zero, so exact): four
-    # compiled decode programs per kind instead of one per bit width, and
-    # no zero-plane block for constant baskets
-    bits_max = min(32, max(1, -(-max(p["bits"] for p in parts_list) // 8)) * 8)
-    wpp = [p["n_pad"] // 32 for p in parts_list]
-    w_max = max(wpp)
-    # lane-align word count (128-lane VPU)
-    w_max = int(-(-w_max // 128) * 128)
-
     N = len(parts_list)
-    planes = np.zeros((N, bits_max, w_max), dtype=np.uint32)
-    firsts = np.zeros((N,), dtype=np.uint32)
-    for i, p in enumerate(parts_list):
-        pw = p["planes"].reshape(max(p["bits"], 1), -1)
-        planes[i, : pw.shape[0], : pw.shape[1]] = pw
-        firsts[i] = p["first"]
+    with tr.span("decode_prep", kind="decode_prep") as sp:
+        # plane count in buckets of 8 (extra planes are zero, so exact):
+        # four compiled decode programs per kind instead of one per bit
+        # width, and no zero-plane block for constant baskets
+        bits_max = min(32, max(1, -(-max(p["bits"] for p in parts_list) // 8)) * 8)
+        wpp = [p["n_pad"] // 32 for p in parts_list]
+        w_max = max(wpp)
+        # lane-align word count (128-lane VPU)
+        w_max = int(-(-w_max // 128) * 128)
+        planes = np.zeros((N, bits_max, w_max), dtype=np.uint32)
+        firsts = np.zeros((N,), dtype=np.uint32)
+        for i, p in enumerate(parts_list):
+            pw = p["planes"].reshape(max(p["bits"], 1), -1)
+            planes[i, : pw.shape[0], : pw.shape[1]] = pw
+            firsts[i] = p["first"]
+        if tr.enabled:
+            sp["baskets"] = N
 
     _note_dispatch(("decode", kind, planes.shape, bool(use_pallas)))
-    if use_pallas:
-        out = _bd.basket_decode(
+    decode = _bd.basket_decode if use_pallas else _bd.basket_decode_ref
+    extra = {"interpret": interpret} if use_pallas else {}
+    with tr.span("device_launch", kind="device_launch") as sp:
+        out = decode(
             jnp.asarray(planes),
             jnp.asarray(firsts),
             kind=kind,
             n_bits=bits_max,
             out_dtype=out_dtype,
-            interpret=interpret,
+            **extra,
         )
-    else:
-        out = _bd.basket_decode_ref(
-            jnp.asarray(planes),
-            jnp.asarray(firsts),
-            kind=kind,
-            n_bits=bits_max,
-            out_dtype=out_dtype,
-        )
-    out = np.asarray(out)
+        if tr.enabled:
+            sp["op"] = "basket_decode"
+            sp["h2d_bytes"] = planes.nbytes + firsts.nbytes
+    with tr.span("device_wait", kind="device_wait") as sp:
+        out = np.asarray(out)
+        if tr.enabled:
+            sp["op"] = "basket_decode"
+            sp["d2h_bytes"] = out.nbytes
+            sp["arrays"] = 1
     return [out[i, : p["n"]] for i, p in enumerate(parts_list)]
 
 

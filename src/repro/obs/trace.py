@@ -24,29 +24,60 @@ constraints, in order:
     timestamps, one ``pid`` per traced process/job).
 
 Span taxonomy (the ``kind`` field): ``query``, ``plan``, ``window``,
-``cascade_stage``, ``fetch``, ``decode``, ``decode_device`` (the
+``load_window`` (a window's phase-1 load: fetch + decode of its filter
+or output set), ``phase2`` (a surviving window's output-branch fetch and
+survivor selection), ``cascade_stage``, ``fetch`` (one store read round,
+attrs: branches/bytes), ``decode``, ``decode_device`` (the
 backend-selected on-device basket decode, DESIGN.md §16), ``kernel``,
 ``device_batch`` (one per window-batched cascade dispatch group, attrs:
 windows/pad_windows/pad_events), ``write``, ``shard``, ``merge``,
-``job``, ``admission``, ``queue``, ``settle``, ``tenant``, and the
-fault-tolerance kinds ``retry`` (one per re-issued shard, attrs:
-failed/used node), ``hedge`` (one per hedged shard, attrs: outcome
-won/lost/cancelled), ``recover`` (one per journal-recovered job, attrs:
-resume_skip).  See DESIGN.md §13–14, §16.
+``job``, ``admission``, ``queue``, ``settle``, and the fault-tolerance
+kinds ``retry`` (one per re-issued shard, attrs: failed/used node),
+``hedge`` (one per hedged shard, attrs: outcome won/lost/cancelled),
+``recover`` (one per journal-recovered job, attrs: resume_skip).
+
+The host–device boundary has four kinds of its own, recorded where the
+work happens (attrs are filled only when the tracer is enabled):
+``decode_prep`` (basket parsing and plane padding before a device
+decode, attrs: baskets), ``stage_inputs`` (host densification for a
+predicate or cascade kernel, attrs: events/K), ``device_launch`` (the
+host→device uploads plus the kernel enqueue, attrs: op/h2d_bytes) and
+``device_wait`` (one blocking read-back of device results to the host,
+attrs: op/d2h_bytes/arrays).  See DESIGN.md §13–14, §16.
+
+**Profiler mirror.**  An enabled tracer also mirrors every span it opens
+live (``span``/``begin``; not ``add_span`` or adopted spans) into the
+JAX profiler's host trace as a ``jax.profiler.TraceAnnotation`` named by
+the span's kind — only when JAX is already imported, so ``obs`` keeps no
+dependency on it.  A profiled run then shows the program's spans beside
+the device's ``XLA Ops`` on the profiler's own clock.  The mirror never
+touches the exported spans, and :data:`NULL_TRACER` mirrors nothing.
 """
 
 from __future__ import annotations
 
 import itertools
 import json
+import sys
 import threading
 import time
+
+
+def _profiler_annotation(kind: str):
+    """Open a ``jax.profiler.TraceAnnotation`` named ``kind`` where JAX's
+    profiler module is already imported; ``None`` otherwise."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None:
+        return None
+    ann = prof.TraceAnnotation(kind)
+    ann.__enter__()
+    return ann
 
 
 class Span:
     """One timed node of the trace tree.  ``t1 is None`` while open."""
 
-    __slots__ = ("sid", "parent", "name", "kind", "t0", "t1", "attrs")
+    __slots__ = ("sid", "parent", "name", "kind", "t0", "t1", "attrs", "mirror")
 
     def __init__(self, sid, parent, name, kind, t0, t1=None, attrs=None):
         self.sid = sid
@@ -56,6 +87,12 @@ class Span:
         self.t0 = t0
         self.t1 = t1
         self.attrs = attrs if attrs is not None else {}
+        self.mirror = None  # the open profiler annotation, if any
+
+    def close_mirror(self) -> None:
+        if self.mirror is not None:
+            self.mirror.__exit__(None, None, None)
+            self.mirror = None
 
     def __setitem__(self, key, value):
         self.attrs[key] = value
@@ -96,11 +133,13 @@ class _SpanCM:
         st = tr._stack()
         pid = self._parent if self._parent is not None else (st[-1] if st else None)
         self._span = tr._new(self._name, self._kind, pid, tr.now(), None, self._attrs)
+        self._span.mirror = _profiler_annotation(self._kind)
         st.append(self._span.sid)
         return self._span
 
     def __exit__(self, *exc) -> bool:
         tr, sp = self._tr, self._span
+        sp.close_mirror()
         sp.t1 = tr.now()
         st = tr._stack()
         if sp.sid in st:
@@ -166,6 +205,7 @@ class Tracer:
         st = self._stack()
         pid = parent if parent is not None else (st[-1] if st else None)
         sp = self._new(name, kind, pid, self.now(), None, attrs)
+        sp.mirror = _profiler_annotation(kind)
         st.append(sp.sid)
         return sp.sid
 
@@ -177,6 +217,7 @@ class Tracer:
         if sp is None:
             return
         if sp.t1 is None:
+            sp.close_mirror()
             sp.t1 = self.now()
         if attrs:
             sp.attrs.update(attrs)

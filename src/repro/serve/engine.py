@@ -250,7 +250,9 @@ class SharedScanEngine:
             lb, ls = Breakdown(), FetchStats()
             # prefetch worker threads never touch the consumer span stack
             ltr = NULL_TRACER if self.pipeline == "threads" else tr
-            lsid = ltr.begin("load_window", kind="fetch", window=start // chunk)
+            lsid = ltr.begin(
+                "load_window", kind="load_window", window=start // chunk
+            )
             data = _decode_branches(
                 store, load_union, start, stop, lb, ls, coalesce=True,
                 tracer=ltr,
@@ -432,6 +434,7 @@ class SharedScanEngine:
                                 payload_branches=plan.payload_branches,
                                 K=pad_K[i],
                                 pad_to=chunk,
+                                tracer=tr,
                             )
                         else:
                             from repro.core.query import eval_stage
@@ -446,7 +449,7 @@ class SharedScanEngine:
                 if k == 0:
                     continue
                 n_passed[i] += k
-                p2sid = tr.begin("phase2", kind="fetch", tenant=i, window=wi)
+                p2sid = tr.begin("phase2", kind="phase2", tenant=i, window=wi)
                 if ex is not None and data is not None:
                     # phase 2 through the shared ledger: baskets any stage
                     # (or an earlier tenant) already moved are not re-paid
