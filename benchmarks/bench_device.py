@@ -110,7 +110,7 @@ def _bench_decode(store: EventStore) -> None:
     csv_row("device/decode/host", host_s * 1e6, f"{n} baskets, numpy codec")
     csv_row(
         "device/decode/device", dev_s * 1e6,
-        f"{n} baskets, one kernel dispatch per plane group; bit-identical",
+        f"{n} baskets, one launch per header group, one read-back; bit-identical",
     )
 
     # the fallback contract: a non-bitpack store asked for device decode

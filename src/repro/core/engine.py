@@ -214,7 +214,7 @@ def _decode_branches(
     """
     tr = tracer if tracer is not None else NULL_TRACER
     data: dict[str, np.ndarray] = dict(preloaded or {})
-    # counts branches must decode before jagged values they describe
+    # counts branches must be sliced before the jagged values they describe
     order = sorted(names, key=lambda n: 0 if not store.branches[n].jagged else 1)
     # one coalesced read round for the whole branch set (TTreeCache model;
     # the store owns the request accounting — DESIGN.md §2b)
@@ -231,16 +231,17 @@ def _decode_branches(
         else "decode"
     )
     dsid = tr.begin("decode", kind=dkind)
-    for name in order:
-        blobs = window[name]
-        parts = []
-        with _Timer(breakdown, "decompress"):
-            decoded = store.decode_blobs(
-                name, [blob for _, blob in blobs], tracer=tr
-            )
-        with _Timer(breakdown, "deserialize"):
+    # one decode round for the whole branch set; only the slicing below
+    # needs the counts branches first
+    with _Timer(breakdown, "decompress"):
+        decoded = store.decode_round(
+            {name: [blob for _, blob in window[name]] for name in order}, tracer=tr
+        )
+    with _Timer(breakdown, "deserialize"):
+        for name in order:
             br = store.branches[name]
-            for (meta, _), vals in zip(blobs, decoded):
+            parts = []
+            for (meta, _), vals in zip(window[name], decoded[name]):
                 if not br.jagged:
                     lo = max(start - meta.first_entry, 0)
                     hi = min(stop - meta.first_entry, meta.n_entries)
@@ -260,9 +261,7 @@ def _decode_branches(
                         lead = 0
                     parts.append(vals[lead : lead + gc.sum()])
             data[name] = (
-                np.concatenate(parts)
-                if parts
-                else np.empty(0, dtype=store.branches[name].np_dtype())
+                np.concatenate(parts) if parts else np.empty(0, dtype=br.np_dtype())
             )
     tr.end(dsid)
     return data

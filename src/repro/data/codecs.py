@@ -269,23 +269,27 @@ def decode_basket(blob: bytes, codec: str, dtype) -> np.ndarray:
 def decode_basket_batch(
     blobs: list, codec: str, dtype, backend: str = "host", tracer=None
 ) -> list:
-    """Decode a list of basket blobs in one round (DESIGN.md §16).
+    """Decode one round of basket blobs, of one branch or of many
+    (DESIGN.md §16c).
 
-    ``backend="host"`` (or any codec without a device decode) loops the
-    host reference decoder.  ``backend="device"`` with the ``bitpack``
-    codec ships the compressed *plane words* — not decoded columns —
-    across the host→device boundary and decodes them on the kernel tier
-    (``repro.kernels.ops.basket_decode_batch``: the Pallas kernel on
-    TPU, its jitted jnp mirror elsewhere), grouped by codec kind so each
-    group is one dispatch.  Output order matches ``blobs`` and is
-    bit-identical to the host reference for every kind (int zigzag-delta
-    prefix sums are wrap-exact int32, float prefix-xor is exact, bools
-    and raw literals are identity).  ``tracer`` records the parsing as
-    ``decode_prep`` and is passed on to each device call.
+    ``dtype`` is one dtype for every blob or a sequence with one per
+    blob.  ``backend="host"`` (or any codec without a device decode)
+    loops the host reference decoder.  ``backend="device"`` with the
+    ``bitpack`` codec ships the compressed *plane words* — not decoded
+    columns — across the host→device boundary and decodes the whole
+    round on the kernel tier (``repro.kernels.ops.basket_decode_batch``:
+    the Pallas kernel on TPU, its jitted jnp mirror elsewhere): one
+    launch per group of like headers, then one read-back for all of
+    them.  Output order matches ``blobs`` and is bit-identical to the
+    host reference for every kind (int zigzag-delta prefix sums are
+    wrap-exact int32, float prefix-xor is exact, bools and raw literals
+    are identity).  ``tracer`` records the parsing as ``decode_prep``
+    and is passed on to the device round.
     """
+    dtypes = list(dtype) if isinstance(dtype, (list, tuple)) else [dtype] * len(blobs)
     if backend != "device" or codec != "bitpack":
         decode = CODECS[codec][1]
-        return [decode(blob, dtype) for blob in blobs]
+        return [decode(blob, dt) for blob, dt in zip(blobs, dtypes)]
     from repro.kernels import ops
 
     tr = tracer if tracer is not None else NULL_TRACER
@@ -293,20 +297,7 @@ def decode_basket_batch(
         parts = [bitpack_raw_parts(blob) for blob in blobs]
         if tr.enabled:
             sp["baskets"] = len(blobs)
-    out: list = [None] * len(blobs)
-    groups: dict[int, list[int]] = {}
-    for i, p in enumerate(parts):
-        if p["n"] == 0:
-            out[i] = np.empty(0, dtype=dtype)
-        else:
-            groups.setdefault(p["kind"], []).append(i)
-    for _kind, idxs in sorted(groups.items()):
-        decoded = ops.basket_decode_batch(
-            [parts[i] for i in idxs], dtype, tracer=tr
-        )
-        for i, vals in zip(idxs, decoded):
-            out[i] = np.asarray(vals)
-    return out
+    return ops.basket_decode_batch(parts, dtypes, tracer=tr)
 
 
 # ---------------------------------------------------------------------------

@@ -59,6 +59,10 @@ INTEGRITY_VERSION = 1
 # Default capacity (in baskets) of the per-store decoded-basket LRU.
 DECODE_CACHE_BASKETS = 64
 
+# An LRU slot reserved by a miss whose decode is still in flight; a
+# lookup that finds it counts a miss, as before the slot is filled.
+_PENDING = object()
+
 
 class CorruptBasket(RuntimeError):
     """A fetched basket blob failed its integrity digest.
@@ -682,8 +686,9 @@ class EventStore:
             self._decode_backend_resolved = backend
         return self._decode_backend_resolved
 
-    def _decode_batch(self, name: str, blobs: list, dtype, tracer=None) -> list:
-        """Backend-dispatched decode of one branch's blobs (no cache).
+    def _decode_batch(self, blobs: list, dtypes: list, tracer=None) -> list:
+        """Backend-dispatched decode of one round's blobs (no cache);
+        ``dtypes`` holds one dtype per blob.
 
         The device tier covers the bitpack codec only; other codecs fall
         back to the host reference, counted in ``decode_fallbacks``.  A
@@ -695,7 +700,7 @@ class EventStore:
         if backend == "device" and blobs:
             if self.codec == "bitpack":
                 vals = decode_basket_batch(
-                    blobs, self.codec, dtype, backend="device", tracer=tracer
+                    blobs, self.codec, dtypes, backend="device", tracer=tracer
                 )
                 with self._decode_lock:
                     self.decode_device_baskets += len(blobs)
@@ -705,7 +710,9 @@ class EventStore:
                     self.decode_fallbacks += len(blobs)
         with self._decode_lock:
             self.decode_host_baskets += len(blobs)
-        return [decode_basket(blob, self.codec, dtype) for blob in blobs]
+        return [
+            decode_basket(blob, self.codec, dt) for blob, dt in zip(blobs, dtypes)
+        ]
 
     def decode_blob(self, name: str, blob: bytes) -> np.ndarray:
         """Decode one basket blob, memoized through a small per-store LRU.
@@ -720,44 +727,76 @@ class EventStore:
         return self.decode_blobs(name, [blob])[0]
 
     def decode_blobs(self, name: str, blobs: list, tracer=None) -> list:
-        """Decode a list of basket blobs for one branch in one round.
+        """Decode a list of basket blobs for one branch: a round of one
+        branch (:meth:`decode_round`)."""
+        return self.decode_round({name: list(blobs)}, tracer=tracer)[name]
 
-        The batch form of :meth:`decode_blob` (same LRU, same freezing):
-        cache misses decode together through the backend-selected tier
-        (:meth:`_decode_batch`), so a device-backed store pays one kernel
-        dispatch per fetch round instead of one per basket.  ``tracer``
-        receives the device tier's host–device spans.
+    def decode_round(self, blobs_by_name: dict, tracer=None) -> dict:
+        """Decode one fetch round: every branch's basket blobs together.
+
+        ``blobs_by_name`` maps branch -> blobs; the result maps branch ->
+        decoded arrays in the same order.  The LRU of :meth:`decode_blob`
+        is consulted basket by basket, branch by branch in the mapping's
+        order, exactly as one :meth:`decode_blobs` call per branch would
+        consult it: same keys, hits, misses, byte counts and evictions (a
+        miss reserves its slot at once and is filled after the decode, so
+        what a later branch finds is what it found before).  Then every
+        miss of every branch decodes in one call of the backend-selected
+        tier (:meth:`_decode_batch`): on the device tier one launch per
+        group of like baskets and one blocking read-back for the round.
+        ``tracer`` receives the device tier's host–device spans.
         """
-        dtype = self.branches[name].np_dtype()
-        if self.decode_cache_baskets <= 0:
-            return self._decode_batch(name, list(blobs), dtype, tracer=tracer)
-        out: list = [None] * len(blobs)
-        misses: list[int] = []
-        with self._decode_lock:
-            for i, blob in enumerate(blobs):
-                cached = self._decode_cache.get((name, blob))
-                if cached is not None:
-                    self._decode_cache.move_to_end((name, blob))
-                    self.decode_cache_hits += 1
-                    self.decode_cache_hit_bytes += cached.nbytes
-                    out[i] = cached
-                else:
-                    self.decode_cache_misses += 1
-                    misses.append(i)
-        if misses:
-            decoded = self._decode_batch(
-                name, [blobs[i] for i in misses], dtype, tracer=tracer
-            )
+        names = list(blobs_by_name)
+        dtype = {n: self.branches[n].np_dtype() for n in names}
+        out = {n: [None] * len(blobs_by_name[n]) for n in names}
+        misses: list[tuple[str, int]] = []
+        cached_round = self.decode_cache_baskets > 0
+        if not cached_round:
+            misses = [(n, i) for n in names for i in range(len(blobs_by_name[n]))]
+        else:
             with self._decode_lock:
-                for i, vals in zip(misses, decoded):
+                for n in names:
+                    blobs = blobs_by_name[n]
+                    first = len(misses)
+                    for i, blob in enumerate(blobs):
+                        cached = self._decode_cache.get((n, blob))
+                        if cached is not None and cached is not _PENDING:
+                            self._decode_cache.move_to_end((n, blob))
+                            self.decode_cache_hits += 1
+                            self.decode_cache_hit_bytes += cached.nbytes
+                            out[n][i] = cached
+                        else:
+                            self.decode_cache_misses += 1
+                            misses.append((n, i))
+                    for _, i in misses[first:]:
+                        self._decode_cache[(n, blobs[i])] = _PENDING
+                        self._decode_cache.move_to_end((n, blobs[i]))
+                    while len(self._decode_cache) > self.decode_cache_baskets:
+                        self._decode_cache.popitem(last=False)
+        if not misses:
+            return out
+        keys = [(n, blobs_by_name[n][i]) for n, i in misses]
+        try:
+            decoded = self._decode_batch(
+                [blob for _, blob in keys], [dtype[n] for n, _ in keys], tracer=tracer
+            )
+        except BaseException:
+            if cached_round:
+                with self._decode_lock:
+                    for key in keys:
+                        if self._decode_cache.get(key) is _PENDING:
+                            del self._decode_cache[key]
+            raise
+        for (n, i), vals in zip(misses, decoded):
+            out[n][i] = vals
+        if cached_round:
+            with self._decode_lock:
+                for key, vals in zip(keys, decoded):
                     if vals.flags.writeable:
                         vals.flags.writeable = False
                     self.decode_cache_miss_bytes += vals.nbytes
-                    self._decode_cache[(name, blobs[i])] = vals
-                    self._decode_cache.move_to_end((name, blobs[i]))
-                    out[i] = vals
-                while len(self._decode_cache) > self.decode_cache_baskets:
-                    self._decode_cache.popitem(last=False)
+                    if key in self._decode_cache:  # reserved, not evicted since
+                        self._decode_cache[key] = vals
         return out
 
     def decode_backend_stats(self) -> dict:

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.data.codecs import KIND_RAW_F32
 from repro.kernels import basket_decode as _bd
 from repro.kernels import flash_attention as _fa
 from repro.kernels import predicate_eval as _pe
@@ -144,70 +145,110 @@ def stream_compact(payload, mask, interpret=None):
     return packed[:E], count
 
 
+def _ladder(x: int, quantum: int) -> int:
+    """``x`` rounded up on a quarter-octave ladder of ``quantum``
+    multiples: steps of ``quantum`` while below ``8 * quantum``, then a
+    quarter of the power of two below ``x``, so padding stays under 25%
+    while the number of distinct shapes grows with ``log(x)``."""
+    step = max(quantum, (1 << (max(x, 1).bit_length() - 1)) // 4)
+    return -(-x // step) * step
+
+
+def _plane_bucket(bits: int) -> int:
+    """Bit planes in buckets of 8 (extra planes are zero, so exact):
+    four decode programs per kind instead of one per bit width, and no
+    zero-plane block for constant baskets."""
+    return min(32, max(1, -(-bits // 8)) * 8)
+
+
 def basket_decode_batch(
-    parts_list, out_dtype, interpret=None, use_pallas=None, tracer=None
+    parts_list, out_dtypes, interpret=None, use_pallas=None, tracer=None
 ):
-    """Decode a batch of ``bitpack_raw_parts`` dicts of the same kind.
+    """Decode a round of ``bitpack_raw_parts`` dicts in one device round
+    trip.
 
-    Pads plane counts/words to the batch max, runs the decode once on the
-    device tier — the Pallas kernel on TPU, its jitted jnp mirror
-    (:func:`repro.kernels.basket_decode.basket_decode_ref`) elsewhere —
-    and returns a list of correctly-sized arrays, bit-identical to the
-    host codec reference (``repro.data.codecs.bitpack_decode``).
+    ``out_dtypes`` holds one dtype per basket.  Baskets are grouped by
+    what their headers say — codec kind, output dtype, bit-plane bucket
+    of 8 and a word-count bucket (:func:`_ladder` over 128-word lanes, so
+    flat baskets are never padded to a jagged branch's width) — and each
+    group, its basket count padded on the same ladder with zero planes,
+    is one launch of the decode: the Pallas kernel on TPU, its jitted jnp mirror
+    (:func:`repro.kernels.basket_decode.basket_decode_ref`) elsewhere.
+    Every group is launched before one blocking read-back of all of
+    them.  Raw-f32 literals pass through on the host and zero-length
+    baskets come back empty; nothing of theirs crosses.  The list
+    returned is in input order and bit-identical to the host codec
+    reference (``repro.data.codecs.bitpack_decode``).
 
-    ``tracer`` records the host–device boundary of the call: the plane
-    padding (``decode_prep``), the upload and enqueue (``device_launch``)
-    and the blocking read-back (``device_wait``).
+    ``tracer`` records the host–device boundary: grouping and padding
+    (``decode_prep``), one ``device_launch`` per group (``baskets``,
+    ``h2d_bytes``) and one ``device_wait`` for the round (``arrays`` =
+    the number of groups, ``d2h_bytes``).
     """
     tr = tracer if tracer is not None else NULL_TRACER
     interpret = default_interpret() if interpret is None else interpret
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    kind = parts_list[0]["kind"]
-    assert all(p["kind"] == kind for p in parts_list)
-    if kind == 3:  # KIND_RAW_F32: literals — passthrough, nothing to decode
-        return [p["raw"].astype(np.dtype(out_dtype)) for p in parts_list]
-    N = len(parts_list)
+    out: list = [None] * len(parts_list)
+    groups: dict[tuple, list[int]] = {}
+    batches = []
     with tr.span("decode_prep", kind="decode_prep") as sp:
-        # plane count in buckets of 8 (extra planes are zero, so exact):
-        # four compiled decode programs per kind instead of one per bit
-        # width, and no zero-plane block for constant baskets
-        bits_max = min(32, max(1, -(-max(p["bits"] for p in parts_list) // 8)) * 8)
-        wpp = [p["n_pad"] // 32 for p in parts_list]
-        w_max = max(wpp)
-        # lane-align word count (128-lane VPU)
-        w_max = int(-(-w_max // 128) * 128)
-        planes = np.zeros((N, bits_max, w_max), dtype=np.uint32)
-        firsts = np.zeros((N,), dtype=np.uint32)
-        for i, p in enumerate(parts_list):
-            pw = p["planes"].reshape(max(p["bits"], 1), -1)
-            planes[i, : pw.shape[0], : pw.shape[1]] = pw
-            firsts[i] = p["first"]
+        for i, (p, dt) in enumerate(zip(parts_list, map(np.dtype, out_dtypes))):
+            if p["n"] == 0:
+                out[i] = np.empty(0, dtype=dt)
+            elif p["kind"] == KIND_RAW_F32:
+                out[i] = p["raw"].astype(dt, copy=False)
+            else:
+                key = (
+                    p["kind"], dt, _plane_bucket(p["bits"]),
+                    _ladder(p["n_pad"] // 32, 128),
+                )
+                groups.setdefault(key, []).append(i)
+        for key, idxs in groups.items():
+            _kind, _dt, n_bits, words = key
+            planes = np.zeros((_ladder(len(idxs), 1), n_bits, words), np.uint32)
+            firsts = np.zeros((planes.shape[0],), np.uint32)
+            for j, i in enumerate(idxs):
+                p = parts_list[i]
+                pw = p["planes"].reshape(max(p["bits"], 1), -1)
+                planes[j, : pw.shape[0], : pw.shape[1]] = pw
+                firsts[j] = p["first"]
+            batches.append((key, idxs, planes, firsts))
         if tr.enabled:
-            sp["baskets"] = N
+            sp["baskets"] = len(parts_list)
 
-    _note_dispatch(("decode", kind, planes.shape, bool(use_pallas)))
     decode = _bd.basket_decode if use_pallas else _bd.basket_decode_ref
     extra = {"interpret": interpret} if use_pallas else {}
-    with tr.span("device_launch", kind="device_launch") as sp:
-        out = decode(
-            jnp.asarray(planes),
-            jnp.asarray(firsts),
-            kind=kind,
-            n_bits=bits_max,
-            out_dtype=out_dtype,
-            **extra,
-        )
-        if tr.enabled:
-            sp["op"] = "basket_decode"
-            sp["h2d_bytes"] = planes.nbytes + firsts.nbytes
+    launched = []
+    for (kind, dt, n_bits, _words), idxs, planes, firsts in batches:
+        _note_dispatch(("decode", kind, dt.str, planes.shape, bool(use_pallas)))
+        with tr.span("device_launch", kind="device_launch") as sp:
+            launched.append(
+                decode(
+                    jnp.asarray(planes),
+                    jnp.asarray(firsts),
+                    kind=kind,
+                    n_bits=n_bits,
+                    out_dtype=dt,
+                    **extra,
+                )
+            )
+            if tr.enabled:
+                sp["op"] = "basket_decode"
+                sp["h2d_bytes"] = planes.nbytes + firsts.nbytes
+                sp["baskets"] = len(idxs)
+    if not launched:
+        return out
     with tr.span("device_wait", kind="device_wait") as sp:
-        out = np.asarray(out)
+        host = jax.device_get(launched)
         if tr.enabled:
             sp["op"] = "basket_decode"
-            sp["d2h_bytes"] = out.nbytes
-            sp["arrays"] = 1
-    return [out[i, : p["n"]] for i, p in enumerate(parts_list)]
+            sp["d2h_bytes"] = sum(h.nbytes for h in host)
+            sp["arrays"] = len(host)
+    for (_key, idxs, _planes, _firsts), vals in zip(batches, host):
+        for j, i in enumerate(idxs):
+            out[i] = vals[j, : parts_list[i]["n"]]
+    return out
 
 
 # ---------------------------------------------------------------------------

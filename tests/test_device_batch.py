@@ -12,7 +12,10 @@ The acceptance contract of the window-batched device path:
     per-batch recompiles);
   * on-device basket decode round-trips every bitpack kind — zigzag
     ints, xor-prefix floats, bools, raw-f32 bail-outs — bit-identically
-    to the host codec, including non-word-aligned basket tails;
+    to the host codec, including non-word-aligned basket tails, and a
+    whole fetch round decodes in one launch per group of like baskets
+    and one read-back, with the decode cache's and tier's ledgers as
+    one round per branch left them;
   * without an accelerator the decode tier resolves to host, and a
     device request over a codec with no device path falls back loudly
     (``decode_fallbacks``) instead of silently.
@@ -278,7 +281,8 @@ def test_device_decode_round_trip(kind, dtype, n):
 
 
 def test_device_decode_mixed_kind_batch():
-    """One decode round over a mixed-kind, mixed-tail blob list."""
+    """One decode round over a mixed-kind, mixed-tail blob list, each
+    blob with its own dtype."""
     rng = np.random.default_rng(3)
     cases = [
         ("int", np.int32, 1001), ("bool", np.bool_, 777),
@@ -286,16 +290,210 @@ def test_device_decode_mixed_kind_batch():
         ("int", np.int32, 2048), ("float", np.float32, 64),
     ]
     blobs = [codecs.bitpack_encode(_kind_values(k, n, rng)) for k, _, n in cases]
-    # per-call dtype is uniform in the store API; group by dtype here
-    for dtype in (np.int32, np.bool_, np.float32):
-        sel = [i for i, (_, dt, _) in enumerate(cases) if dt == dtype]
-        got = codecs.decode_basket_batch(
-            [blobs[i] for i in sel], "bitpack", dtype, backend="device"
+    dtypes = [dt for _, dt, _ in cases]
+    got = codecs.decode_basket_batch(blobs, "bitpack", dtypes, backend="device")
+    for blob, dt, arr in zip(blobs, dtypes, got):
+        np.testing.assert_array_equal(arr, codecs.bitpack_decode(blob, dt))
+
+
+def _round_blobs(rng) -> tuple[list, list]:
+    """Blobs of one fetch round as a store hands them over: flat baskets
+    of 2,048 values and jagged ones of 4-6x as many, ints of several bit
+    widths, floats that stay bit-planes and floats stored as literals,
+    bools and empty baskets."""
+    specs = []
+    for n in (2048, 9000, 12500):
+        for span in (6, 200, 70_000, 2**27):
+            specs.append((rng.integers(0, span, n).astype(np.int32), np.int32))
+        specs.append((rng.random(n) < 0.3, np.bool_))
+        specs.append((_kind_values("float", n, rng), np.float32))
+        specs.append((_kind_values("raw", n, rng), np.float32))
+    specs.append((np.zeros(0, np.int32), np.int32))
+    specs.append((np.zeros(0, np.float32), np.float32))
+    specs.append((rng.integers(0, 6, 2048).astype(np.int32), np.int32))
+    order = rng.permutation(len(specs))
+    return (
+        [codecs.bitpack_encode(specs[i][0]) for i in order],
+        [specs[i][1] for i in order],
+    )
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["mirror", "pallas"])
+def test_device_decode_round_groups_like_baskets(use_pallas):
+    """A round is bit-identical to the per-blob host reference, and
+    decodes in one launch per (kind, dtype, plane bucket, word bucket)
+    group and one read-back of all of them."""
+    from repro.obs import Tracer
+    from repro.serve import ManualClock
+
+    blobs, dtypes = _round_blobs(np.random.default_rng(21))
+    parts = [codecs.bitpack_raw_parts(b) for b in blobs]
+    tr = Tracer(clock=ManualClock())
+    got = ops.basket_decode_batch(parts, dtypes, use_pallas=use_pallas, tracer=tr)
+    for blob, dt, arr in zip(blobs, dtypes, got):
+        want = codecs.bitpack_decode(blob, dt)
+        assert arr.dtype == want.dtype and arr.shape == want.shape
+        np.testing.assert_array_equal(arr, want)
+    groups = {
+        (p["kind"], np.dtype(dt), ops._plane_bucket(p["bits"]),
+         ops._ladder(p["n_pad"] // 32, 128))
+        for p, dt in zip(parts, dtypes)
+        if p["n"] and p["kind"] != codecs.KIND_RAW_F32
+    }
+    launches = [s for s in tr.spans() if s.kind == "device_launch"]
+    [wait] = [s for s in tr.spans() if s.kind == "device_wait"]
+    assert len(launches) == len(groups) == wait.attrs["arrays"] > 3
+    crossing = sum(
+        p["n"] > 0 and p["kind"] != codecs.KIND_RAW_F32 for p in parts
+    )
+    assert sum(s.attrs["baskets"] for s in launches) == crossing
+    assert max(s.sid for s in launches) < wait.sid  # all launched, then one wait
+
+
+def test_decode_ladder_pads_under_a_quarter():
+    for quantum in (1, 128):
+        rungs = {ops._ladder(x, quantum) for x in range(1, 64 * quantum + 1)}
+        for x in range(1, 64 * quantum + 1):
+            up = ops._ladder(x, quantum)
+            assert up >= x and up % quantum == 0
+            assert up - x < max(quantum, x / 4)
+        # the number of shapes grows with log(x), not with x
+        assert len(rungs) <= 8 + 4 * 3
+
+
+def _per_branch_rounds(monkeypatch):
+    """Make a store decode one branch per round, as the engine did
+    before a round covered every branch."""
+    orig = EventStore.decode_round
+
+    def per_branch(self, blobs_by_name, tracer=None):
+        return {
+            n: orig(self, {n: blobs}, tracer=tracer)[n]
+            for n, blobs in blobs_by_name.items()
+        }
+
+    monkeypatch.setattr(EventStore, "decode_round", per_branch)
+
+
+@pytest.mark.parametrize("backend", ["device", "host"])
+@pytest.mark.parametrize("capacity", [64, 0])
+def test_decode_round_counts_like_per_branch_rounds(monkeypatch, backend, capacity):
+    """The decode cache and tier ledgers read the same after a run with
+    one round per fetch as after the same run with one round per branch,
+    and so do the outputs."""
+    from repro.core.engine import SkimEngine
+
+    def run():
+        st = make_nanoaod_like(
+            6 * BASKET, n_hlt=8, n_filler=4, basket_events=BASKET
         )
-        for i, arr in zip(sel, got):
+        st.decode_backend = backend
+        st.decode_cache_baskets = capacity
+        eng = SkimEngine(st, chunk_events=BASKET)
+        res = eng.run(QUERY)
+        again = eng.run(QUERY)  # the last windows' baskets are still cached
+        _assert_same_output(again, res)
+        return res, st.decode_cache_stats(), st.decode_backend_stats()
+
+    res, cache, tier = run()
+    with monkeypatch.context() as m:
+        _per_branch_rounds(m)
+        ref, ref_cache, ref_tier = run()
+    _assert_same_output(res, ref)
+    assert cache == ref_cache
+    assert tier == ref_tier
+    if capacity:
+        assert cache["hits"] > 0
+    assert tier[f"{backend}_baskets"] > 0
+
+
+@pytest.mark.parametrize("backend", ["device", "host"])
+def test_decode_round_lru_sequence_like_per_branch_rounds(monkeypatch, backend):
+    """Rounds over a small LRU that evicts within a round: every round
+    hits, misses and evicts as one round per branch did, basket by
+    basket, and returns the same arrays."""
+    rng = np.random.default_rng(5)
+    n = 4 * BASKET
+    cols = {f"X_{b}": rng.integers(0, 50, n).astype(np.int32) for b in range(6)}
+    cols["F"] = rng.random(n).astype(np.float32)
+    names = sorted(cols)
+    stores = []
+    for _ in range(2):
+        st = EventStore.from_arrays(cols, basket_events=BASKET, decode_backend=backend)
+        st.decode_cache_baskets = 4
+        stores.append(st)
+    rounds = []
+    for _ in range(30):
+        picked = rng.choice(names, rng.integers(1, len(names) + 1), replace=False)
+        rounds.append({
+            str(nm): [stores[0]._blobs[nm][i] for i in sorted(rng.choice(4, 2, replace=False))]
+            for nm in picked
+        })
+    got, want = [], []
+    for r in rounds:
+        got.append(stores[0].decode_round(r))
+        got.append(stores[0].decode_cache_stats())
+    with monkeypatch.context() as m:
+        _per_branch_rounds(m)
+        for r in rounds:
+            want.append(stores[1].decode_round(r))
+            want.append(stores[1].decode_cache_stats())
+    for g, w in zip(got, want):
+        if "hits" in g:
+            assert g == w
+            continue
+        assert g.keys() == w.keys()
+        for nm in g:
+            for a, b in zip(g[nm], w[nm]):
+                np.testing.assert_array_equal(a, b)
+    assert got[-1]["hits"] > 0 and got[-1]["resident"] == 4
+    assert stores[0].decode_backend_stats() == stores[1].decode_backend_stats()
+
+
+def _varying_width_store(n_windows: int) -> EventStore:
+    """Flat int branches whose bit widths change from window to window:
+    in window ``w`` the first ``8 + 5w mod 9`` of 24 need 12 bit planes
+    and the others 4, so the two plane buckets' groups take every size
+    from 8 to 16 baskets within nine windows."""
+    rng = np.random.default_rng(17)
+    n = n_windows * BASKET
+    cols = {f"B_{b:02d}": rng.random(n) < 0.2 for b in range(8)}
+    wide = [8 + (5 * w) % 9 for w in range(n_windows)]
+    for b in range(24):
+        cols[f"I_{b:02d}"] = np.concatenate(
+            [rng.integers(0, 2**10 if b < k else 2**2, BASKET) for k in wide]
+        ).astype(np.int32)
+    return EventStore.from_arrays(cols, basket_events=BASKET, decode_backend="device")
+
+
+def test_decode_programs_stop_growing_across_windows():
+    """Bucketed group shapes: after the first windows of a run whose bit
+    widths vary, no window adds a decode signature to the dispatch
+    ledger, although the exact group sizes keep changing."""
+    n_windows = 24
+    st = _varying_width_store(n_windows)
+    st.decode_cache_baskets = 0
+    names = sorted(st.branches)
+    ops.reset_dispatch_stats()
+    compiles, exact = [], set()
+    for w in range(n_windows):
+        blobs = {n: [blob for _, blob in st.fetch_range(n, w * BASKET, (w + 1) * BASKET)]
+                 for n in names}
+        got = st.decode_round(blobs)
+        for n in names:
             np.testing.assert_array_equal(
-                np.asarray(arr), codecs.bitpack_decode(blobs[i], dtype)
+                got[n][0], codecs.bitpack_decode(blobs[n][0], st.branches[n].np_dtype())
             )
+        sizes = {}
+        for n in names:
+            p = codecs.bitpack_raw_parts(blobs[n][0])
+            key = (p["kind"], ops._plane_bucket(p["bits"]))
+            sizes[key] = sizes.get(key, 0) + 1
+        exact |= set(sizes.items())
+        compiles.append(ops.dispatch_stats()["compiles"])
+    assert compiles[9] == compiles[-1]
+    # without the basket-count ladder every exact size would compile
+    assert compiles[-1] < len(exact)
 
 
 # ---------------------------------------------------------------------------

@@ -4,9 +4,10 @@ Pins the contracts of the ``decode_prep`` / ``stage_inputs`` /
 ``device_launch`` / ``device_wait`` spans on the CPU (the device decode's
 jitted mirror and the fused oracle stand in for the Pallas kernels):
 
-  * every on-device basket decode is one launch and one wait under its
-    ``decode_device`` span, counted against the calls that reached
-    ``ops.basket_decode_batch``, with bytes that cover what crossed;
+  * every decode round that reaches the device is one launch per group
+    of like baskets and one wait under its ``decode_device`` span,
+    counted against the rounds that reached ``ops.basket_decode_batch``,
+    with bytes that cover what crossed;
   * the cascade's device stages — per window and window-batched — record
     staging, launch and wait under their ``cascade_stage`` span;
   * ``fetch`` means one store read: ``load_window``/``phase2`` are kinds
@@ -92,21 +93,25 @@ def _of(tr, kind: str) -> list:
 
 @pytest.fixture()
 def counted(monkeypatch):
-    """Counts the calls that reach the device in ``ops.basket_decode_batch``
-    (raw literal baskets pass through on the host) and the compressed
-    bytes of every basket the store decodes on a cache miss."""
-    seen = {"device_calls": 0, "plane_bytes": 0}
+    """Counts the decode rounds that reach the device in
+    ``ops.basket_decode_batch`` and the baskets that cross in them (raw
+    literal and empty baskets stay on the host), and the compressed bytes
+    of every basket the store decodes on a cache miss."""
+    seen = {"device_rounds": 0, "device_baskets": 0, "plane_bytes": 0}
     orig_batch = ops.basket_decode_batch
     orig_decode = EventStore._decode_batch
 
     def batch(parts_list, *a, **kw):
-        if parts_list[0]["kind"] != codecs.KIND_RAW_F32:
-            seen["device_calls"] += 1
+        crossing = sum(
+            p["n"] > 0 and p["kind"] != codecs.KIND_RAW_F32 for p in parts_list
+        )
+        seen["device_rounds"] += crossing > 0
+        seen["device_baskets"] += crossing
         return orig_batch(parts_list, *a, **kw)
 
-    def decode(self, name, blobs, *a, **kw):
+    def decode(self, blobs, *a, **kw):
         seen["plane_bytes"] += sum(len(b) - HEADER_BYTES for b in blobs)
-        return orig_decode(self, name, blobs, *a, **kw)
+        return orig_decode(self, blobs, *a, **kw)
 
     monkeypatch.setattr(ops, "basket_decode_batch", batch)
     monkeypatch.setattr(EventStore, "_decode_batch", decode)
@@ -114,6 +119,8 @@ def counted(monkeypatch):
 
 
 def test_every_device_decode_is_one_launch_and_one_wait(counted):
+    """One fetch round decodes in one device round trip: a launch per
+    group of like baskets, then a single wait reading all of them."""
     st = _binade_store()
     tr = Tracer(clock=ManualClock())
     res = SkimEngine(st, chunk_events=BASKET).run(QUERY, tracer=tr)
@@ -125,11 +132,18 @@ def test_every_device_decode_is_one_launch_and_one_wait(counted):
         kinds = collections.Counter(c.kind for c in kids[d.sid])
         if kinds:  # a span whose baskets all hit the decode cache has none
             assert kinds["decode_prep"] >= 1
-            assert kinds["device_launch"] == kinds["device_wait"] >= 1
+            assert kinds["device_wait"] == 1
+            assert kinds["device_launch"] >= 1
+            [wait] = [c for c in kids[d.sid] if c.kind == "device_wait"]
+            assert wait.attrs["arrays"] == kinds["device_launch"]
     waits = [s for s in _of(tr, "device_wait") if s.attrs["op"] == "basket_decode"]
     assert all(tr.get(s.parent).kind == "decode_device" for s in waits)
-    assert all(s.attrs["arrays"] == 1 for s in waits)
-    assert len(waits) == counted["device_calls"] > 0
+    assert len(waits) == counted["device_rounds"] > 0
+    launches = [s for s in _of(tr, "device_launch") if s.attrs["op"] == "basket_decode"]
+    assert all(s.attrs["baskets"] >= 1 for s in launches)
+    assert sum(s.attrs["baskets"] for s in launches) == counted["device_baskets"]
+    # grouping engages: fewer launches than baskets decoded on the device
+    assert len(launches) < counted["device_baskets"]
     assert sum(bool(kids[d.sid]) for d in decodes) > len(decodes) // 2
 
 

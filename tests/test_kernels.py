@@ -112,7 +112,7 @@ def test_basket_decode_sweep(dtype, gen, sizes, use_pallas):
     arrs = [gen(n) for n in sizes]
     parts = [bitpack_raw_parts(bitpack_encode(a)) for a in arrs]
     out_dtype = jnp.int32 if dtype == np.int32 else jnp.float32
-    outs = ops.basket_decode_batch(parts, out_dtype, use_pallas=use_pallas)
+    outs = ops.basket_decode_batch(parts, [out_dtype] * len(parts), use_pallas=use_pallas)
     for a, o in zip(arrs, outs):
         np.testing.assert_array_equal(np.asarray(o), a.astype(np.asarray(o).dtype))
 

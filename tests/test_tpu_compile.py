@@ -117,12 +117,15 @@ def test_predicate_eval_batch_compiles(shape, name, K):
 
 @pytest.mark.parametrize("kind", [bd.KIND_INT, bd.KIND_FLOAT, bd.KIND_BOOL])
 @pytest.mark.parametrize("n_bits", [8, 32])
-def test_basket_decode_compiles(shape, kind, n_bits):
+# one basket, and the grouped launches of a decode round: flat baskets,
+# and a jagged branch's wider ones
+@pytest.mark.parametrize("n_baskets,words", [(1, WORDS), (28, WORDS), (7, 640)])
+def test_basket_decode_compiles(shape, kind, n_bits, n_baskets, words):
     _assert_kernel(
         lambda planes, firsts: bd.basket_decode(
             planes, firsts, kind=kind, n_bits=n_bits, interpret=False
         ),
-        shape((1, n_bits, WORDS), jnp.uint32), shape((1,), jnp.uint32),
+        shape((n_baskets, n_bits, words), jnp.uint32), shape((n_baskets,), jnp.uint32),
     )
 
 
