@@ -19,18 +19,36 @@ group:
     event, which carry no prefix), ``"trigger"`` (bits, rate 0.02),
     ``"flag"`` (bits, rate 0.99) or ``"value"`` (the default).
 
-A group's first fields carry the names the query templates read
-(``NAMED``); the rest are ``v<k>``, and by ``k`` a float32 value, a small
-int32 or a bit, as NanoAOD mixes them.  Electron, Muon and Jet keep the
-repository generator's multiplicities and distributions; lumi blocks hold
-1,000 events.
+A collection may also give ``"min"``, a multiplicity floor: its counts
+are then ``min + Poisson(mean - min)``, so ``mean`` stays the mean.
+
+A group's first fields carry the names the query templates read; the
+rest are ``v<k>``, and by ``k`` a float32 value, a small int32 or a bit,
+as NanoAOD mixes them.  A collection or flat group states its named
+fields itself with ``"named"``, an ordered list of
+``{"field", "dist", <params>, "dtype"}`` counted within ``fields``;
+``dist`` is one of
+
+``exponential``  ``scale``, ``offset`` (default 0): ``offset + Exp(scale)``
+``uniform``      ``low``, ``high``
+``abs_normal``   ``loc``, ``scale``: ``|Normal(loc, scale)|``
+``choice``       ``values``: each equally likely
+``bernoulli``    ``p``: true with probability ``p``
+``beta``         ``a``, ``b``
+``poisson``      ``lam``
+
+and ``dtype`` a NumPy type name (``float32``, ``int32``, ``bool``).  A
+group that states none takes ``NAMED``'s fields and their fixed draws:
+Electron, Muon and Jet keep the repository generator's multiplicities
+and distributions.  Lumi blocks hold 1,000 events.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-#: the named leading fields of a group, as the query templates read them
+#: the named leading fields of a group that declares none, as the query
+#: templates read them
 NAMED = {
     "Electron": ("pt", "eta", "phi", "mass", "charge", "mvaId"),
     "Muon": ("pt", "eta", "phi", "mass", "charge", "tightId"),
@@ -88,9 +106,40 @@ def _generic(rng: np.random.Generator, kind: str, k: int, n: int) -> np.ndarray:
     return rng.standard_normal(n, dtype=np.float32)
 
 
-def _fields(group: str, n_fields: int) -> list[tuple[int, str]]:
-    named = NAMED.get(group, ())
-    return [(k, named[k] if k < len(named) else f"v{k:02d}") for k in range(n_fields)]
+#: the declared draws, ``dist`` -> values from ``(rng, field, n)``
+DISTS = {
+    "exponential": lambda rng, d, n: rng.exponential(d["scale"], n) + d.get("offset", 0.0),
+    "uniform": lambda rng, d, n: rng.uniform(d["low"], d["high"], n),
+    "abs_normal": lambda rng, d, n: np.abs(rng.normal(d["loc"], d["scale"], n)),
+    "choice": lambda rng, d, n: rng.choice(np.asarray(d["values"]), n),
+    "bernoulli": lambda rng, d, n: rng.random(n) < d["p"],
+    "beta": lambda rng, d, n: rng.beta(d["a"], d["b"], n),
+    "poisson": lambda rng, d, n: rng.poisson(d["lam"], n),
+}
+
+
+def _declared(rng: np.random.Generator, field: dict, n: int) -> np.ndarray:
+    if field["dist"] not in DISTS:
+        raise ValueError(
+            f"field {field['field']!r}: unknown dist {field['dist']!r}; known: {sorted(DISTS)}"
+        )
+    return DISTS[field["dist"]](rng, field, n).astype(field["dtype"])
+
+
+def _group(rng: np.random.Generator, name: str, group: dict, kind: str, n: int):
+    """``(field, values)`` of a group's fields in order: its declared
+    ``named`` fields, or ``NAMED``'s, then ``v<k>``."""
+    declared = group.get("named")
+    named = [f["field"] for f in declared] if declared is not None else NAMED.get(name, ())
+    if declared is not None and len(declared) > group["fields"]:
+        raise ValueError(f"{name}: {len(declared)} fields named, {group['fields']} in all")
+    for k in range(group["fields"]):
+        if k >= len(named):
+            yield f"v{k:02d}", _generic(rng, kind, k, n)
+        elif declared is not None:
+            yield named[k], _declared(rng, declared[k], n)
+        else:
+            yield named[k], _named(rng, name, named[k], n)
 
 
 def nanoaod_columns(store: dict, seed: int) -> tuple[dict[str, np.ndarray], dict[str, str]]:
@@ -103,16 +152,11 @@ def nanoaod_columns(store: dict, seed: int) -> tuple[dict[str, np.ndarray], dict
     columns: dict[str, np.ndarray] = {}
     jagged: dict[str, str] = {}
     for coll in store["collections"]:
-        name = coll["name"]
-        counts = rng.poisson(coll["mean"], n_events).astype(np.int32)
-        total = int(counts.sum())
+        name, floor = coll["name"], coll.get("min", 0)
+        counts = (floor + rng.poisson(coll["mean"] - floor, n_events)).astype(np.int32)
         columns[f"n{name}"] = counts
-        for k, var in _fields(name, coll["fields"]):
-            columns[f"{name}_{var}"] = (
-                _named(rng, name, var, total)
-                if k < len(NAMED.get(name, ()))
-                else _generic(rng, "value", k, total)
-            )
+        for var, values in _group(rng, name, coll, "value", int(counts.sum())):
+            columns[f"{name}_{var}"] = values
             jagged[f"{name}_{var}"] = f"n{name}"
     for group in store["flat"]:
         prefix, kind = group["prefix"], group.get("kind", "value")
@@ -123,10 +167,6 @@ def nanoaod_columns(store: dict, seed: int) -> tuple[dict[str, np.ndarray], dict
             ).astype(np.int32)
             columns["event"] = np.arange(n_events, dtype=np.int32)
             continue
-        for k, var in _fields(prefix, group["fields"]):
-            columns[f"{prefix}_{var}"] = (
-                _named(rng, prefix, var, n_events)
-                if k < len(NAMED.get(prefix, ()))
-                else _generic(rng, kind, k, n_events)
-            )
+        for var, values in _group(rng, prefix, group, kind, n_events):
+            columns[f"{prefix}_{var}"] = values
     return columns, jagged

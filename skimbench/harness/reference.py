@@ -2,12 +2,13 @@
 
 A straightforward NumPy evaluation of the query language over the
 generated columns, written from the query semantics and importing
-nothing of the program:
+nothing of the program.  Each node type has a rule of its own in
+``rules/<type>.py``, found by the type's name as metrics are:
 
-* ``preselection`` and event ``cut``: a flat branch compared with a
-  threshold;
+* ``cut``: a flat branch compared with a threshold (every
+  ``preselection`` node is one);
 * ``object``: objects of a collection that pass every cut, counted per
-  event against ``min_count``;
+  event against ``min_count`` (every ``object`` node is one);
 * ``ht``: the sum of ``var`` over the objects that pass ``object_cuts``;
 * ``any``: the OR of boolean branches, an absent branch counting false;
 * ``mass``: the invariant mass of the leading pair (the two highest-pt
@@ -16,10 +17,19 @@ nothing of the program:
 * ``deltaR``: the distance in (eta, phi) of the leading pair, phi
   wrapped into [-pi, pi).
 
+An ``event`` node's type is its ``type``, ``cut`` if it gives none.  A
+rule module gives ``branches(node, tier, present)``, the branches the
+node reads (counts branches included), and ``evaluate(sel, tier,
+node)``, the node's ``(mask, margin)`` over a :class:`Selection`.  A
+configuration whose queries need another node type adds its rule as a
+new file; a type with no file is an error that names the file.
+
 Events without a full pair fail ``mass`` and ``deltaR``.  Derived
-quantities (HT, mass, deltaR) are computed in ``dtype``: float64 for the
-reference, bfloat16 for the control.  The mass is the textbook
-four-vector form, E = sqrt(pt^2 cosh^2(eta) + m^2).
+quantities (HT, mass, deltaR) are computed in the selection's ``dtype``
+(``Selection._f``): float64 for the reference, bfloat16 for the
+control.  The shared kinematics live here for rules to import: the
+leading objects and pair, the textbook four-vector mass (E =
+sqrt(pt^2 cosh^2(eta) + m^2)) and the phi wrap.
 
 The output set is the query's ``branches`` patterns matched against
 the store's branch names, with ``HLT_*`` standing for the five named
@@ -32,8 +42,14 @@ from __future__ import annotations
 
 import fnmatch
 import json
+import os
 
 import numpy as np
+
+from harness.spec import BENCH_DIR, load_module
+
+#: where ``rules/<type>.py`` are found
+RULES_DIR = os.path.join(BENCH_DIR, "rules")
 
 #: output patterns that stand for a fixed minimal set of branches
 MINIMAL_SETS = {
@@ -57,30 +73,32 @@ OPS = {
     "abs>": lambda x, v: np.greater(np.abs(x), v),
 }
 
-PAIR_VARS = {"mass": ("pt", "eta", "phi", "mass"), "deltaR": ("pt", "eta", "phi")}
+_RULES: dict[str, object] = {}  # rule file path -> its module
+
+
+def node_type(tier: str, node: dict) -> str:
+    """The node type whose rule evaluates ``node`` in ``tier``."""
+    if tier == "preselection":
+        return "cut"
+    if tier == "object":
+        return "object"
+    return node.get("type", "cut")
+
+
+def rule(kind: str):
+    """The module of ``rules/<kind>.py``; an error naming the file where
+    there is none."""
+    path = os.path.join(RULES_DIR, f"{kind}.py")
+    if path not in _RULES:
+        if not kind.isidentifier() or not os.path.isfile(path):
+            raise ValueError(f"the reference has no rule for node type {kind!r}: no file {path}")
+        _RULES[path] = load_module(path, f"skimbench_rule_{kind}")
+    return _RULES[path]
 
 
 def node_branches(node: dict, tier: str, present) -> set[str]:
     """Branches one selection node reads (counts branches included)."""
-    if tier == "preselection" or node.get("type", "cut") == "cut" and "branch" in node:
-        return {node["branch"]}
-    if tier == "object":
-        c = node["collection"]
-        return {f"n{c}"} | {f"{c}_{cut['var']}" for cut in node.get("cuts", [])}
-    kind = node.get("type", "cut")
-    if kind == "ht":
-        c = node["collection"]
-        return {f"n{c}", f"{c}_{node.get('var', 'pt')}"} | {
-            f"{c}_{cut['var']}" for cut in node.get("object_cuts", [])
-        }
-    if kind == "any":
-        return {b for b in node["branches"] if b in present}
-    if kind in PAIR_VARS:
-        out: set[str] = set()
-        for c in set(node["collections"]):
-            out |= {f"n{c}"} | {f"{c}_{v}" for v in PAIR_VARS[kind]}
-        return out
-    raise ValueError(f"the reference has no rule for node {node!r}")
+    return rule(node_type(tier, node)).branches(node, tier, present)
 
 
 def selection_nodes(doc: dict) -> list[tuple[str, dict]]:
@@ -125,7 +143,7 @@ class Columns:
         return total
 
 
-def _leading(cols: Columns, coll: str, k: int):
+def leading(cols: Columns, coll: str, k: int):
     """Indices of the ``k`` highest-pt objects of each event and the mask
     of events that have at least ``k`` objects."""
     pt = cols.columns[f"{coll}_pt"].astype(np.float64)
@@ -170,101 +188,61 @@ class Selection:
         return np.asarray(x).astype(self.dtype)
 
     def _eval(self, tier: str, node: dict):
-        c = self.cols.columns
-        kind = "object" if tier == "object" else node.get("type", "cut")
-        if kind == "cut":
-            return OPS[node["op"]](c[node["branch"]], node["value"]), None
-        if kind == "any":
-            mask = np.zeros(self.cols.n_events, dtype=bool)
-            for b in node["branches"]:
-                if b in c:
-                    mask |= c[b].astype(bool)
-            return mask, None
-        if kind == "object":
-            coll = node["collection"]
-            ok = np.ones(len(self.cols.event_of(coll)), dtype=bool)
-            for cut in node.get("cuts", []):
-                ok &= OPS[cut["op"]](c[f"{coll}_{cut['var']}"], cut["value"])
-            n = np.bincount(self.cols.event_of(coll)[ok], minlength=self.cols.n_events)
-            return n >= node.get("min_count", 1), None
-        if kind == "ht":
-            return self._ht(node)
-        if kind == "mass":
-            q, ok = self._pair_mass(node["collections"])
-            lo, hi = node["window"]
-            inside = ok & (q >= lo) & (q <= hi)
-            margin = np.minimum(np.abs(q - lo) / abs(lo), np.abs(q - hi) / abs(hi))
-            return inside, np.where(ok, margin, np.inf)
-        if kind == "deltaR":
-            q, ok = self._pair_delta_r(node["collections"])
-            v = node["value"]
-            return ok & OPS[node["op"]](q, v), np.where(ok, np.abs(q - v) / abs(v), np.inf)
-        raise ValueError(f"the reference has no rule for node {node!r}")
+        return rule(node_type(tier, node)).evaluate(self, tier, node)
 
-    def _ht(self, node: dict):
-        c = self.cols.columns
-        coll = node["collection"]
-        ev = self.cols.event_of(coll)
-        ok = np.ones(len(ev), dtype=bool)
-        for cut in node.get("object_cuts", []):
-            ok &= OPS[cut["op"]](c[f"{coll}_{cut['var']}"], cut["value"])
-        vals = self._f(c[f"{coll}_{node.get('var', 'pt')}"])
-        # accumulate object by object in storage order, in ``dtype``
-        off = self.cols.offsets(f"n{coll}")
-        slot = np.arange(len(ev)) - off[ev]
-        ht = np.zeros(self.cols.n_events, dtype=self.dtype)
-        for j in range(int(slot.max()) + 1 if len(slot) else 0):
-            sel = (slot == j) & ok
-            ht[ev[sel]] = (ht[ev[sel]] + vals[sel]).astype(self.dtype)
-        v = node["value"]
-        q = ht.astype(np.float64)
-        return OPS[node["op"]](ht, self.dtype(v)), np.abs(q - v) / abs(v)
 
-    def _pair(self, collections, variables):
-        a, b = collections
-        if a == b:
-            (i1, h1), (i2, h2) = _leading(self.cols, a, 2)
-            picks, ok = ((a, i1), (a, i2)), h2
-        else:
-            ((ia, ha),) = _leading(self.cols, a, 1)
-            ((ib, hb),) = _leading(self.cols, b, 1)
-            picks, ok = ((a, ia), (b, ib)), ha & hb
-        c = self.cols.columns
-        out = []
-        for coll, idx in picks:
-            out.append({
-                v: self._f(c[f"{coll}_{v}"][idx] if len(c[f"{coll}_{v}"]) else np.zeros(len(idx)))
-                for v in variables
-            })
-        return out[0], out[1], ok
+def collection_branches(collections, variables) -> set[str]:
+    """The counts branch and ``<collection>_<var>`` of each collection."""
+    out: set[str] = set()
+    for c in set(collections):
+        out |= {f"n{c}"} | {f"{c}_{v}" for v in variables}
+    return out
 
-    def _pair_mass(self, collections):
-        p, q, ok = self._pair(collections, PAIR_VARS["mass"])
-        f = self._f
 
-        def four(o):
-            pt, eta, phi, m = o["pt"], o["eta"], o["phi"], o["mass"]
-            pz = f(pt * np.sinh(eta))
-            e = f(np.sqrt(f(f(f(pt * pt) * f(np.cosh(eta) * np.cosh(eta))) + f(m * m))))
-            return f(pt * np.cos(phi)), f(pt * np.sin(phi)), pz, e
+def leading_pair(sel: Selection, collections, variables):
+    """``(p, q, ok)``: the leading pair's ``variables`` in ``sel.dtype``
+    (the two highest-pt objects of one collection, or each collection's
+    leading object) and the mask of events that have it."""
+    a, b = collections
+    if a == b:
+        (i1, h1), (i2, h2) = leading(sel.cols, a, 2)
+        picks, ok = ((a, i1), (a, i2)), h2
+    else:
+        ((ia, ha),) = leading(sel.cols, a, 1)
+        ((ib, hb),) = leading(sel.cols, b, 1)
+        picks, ok = ((a, ia), (b, ib)), ha & hb
+    c = sel.cols.columns
+    out = []
+    for coll, idx in picks:
+        out.append({
+            v: sel._f(c[f"{coll}_{v}"][idx] if len(c[f"{coll}_{v}"]) else np.zeros(len(idx)))
+            for v in variables
+        })
+    return out[0], out[1], ok
 
-        px1, py1, pz1, e1 = four(p)
-        px2, py2, pz2, e2 = four(q)
-        e, px, py, pz = f(e1 + e2), f(px1 + px2), f(py1 + py2), f(pz1 + pz2)
-        m2 = f(f(e * e) - f(f(px * px) + f(f(py * py) + f(pz * pz))))
-        m = np.sqrt(np.maximum(m2.astype(np.float64), 0.0)).astype(self.dtype)
-        return m.astype(np.float64), ok
 
-    def _pair_delta_r(self, collections):
-        p, q, ok = self._pair(collections, PAIR_VARS["deltaR"])
-        f = self._f
-        deta = f(p["eta"] - q["eta"])
-        dphi = f(p["phi"] - q["phi"])
-        two_pi = f(2 * np.pi)
-        dphi = np.where(dphi >= f(np.pi), f(dphi - two_pi), dphi)
-        dphi = np.where(dphi < f(-np.pi), f(dphi + two_pi), dphi)
-        dr = f(np.sqrt(f(f(deta * deta) + f(dphi * dphi))))
-        return dr.astype(np.float64), ok
+def four_vector_mass(f, p: dict, q: dict) -> np.ndarray:
+    """Invariant mass of two objects (``pt``, ``eta``, ``phi``, ``mass``),
+    every step rounded by ``f``; returned as float64."""
+
+    def four(o):
+        pt, eta, phi, m = o["pt"], o["eta"], o["phi"], o["mass"]
+        pz = f(pt * np.sinh(eta))
+        e = f(np.sqrt(f(f(f(pt * pt) * f(np.cosh(eta) * np.cosh(eta))) + f(m * m))))
+        return f(pt * np.cos(phi)), f(pt * np.sin(phi)), pz, e
+
+    px1, py1, pz1, e1 = four(p)
+    px2, py2, pz2, e2 = four(q)
+    e, px, py, pz = f(e1 + e2), f(px1 + px2), f(py1 + py2), f(pz1 + pz2)
+    m2 = f(f(e * e) - f(f(px * px) + f(f(py * py) + f(pz * pz))))
+    return f(np.sqrt(np.maximum(m2.astype(np.float64), 0.0))).astype(np.float64)
+
+
+def wrap_phi(f, dphi: np.ndarray) -> np.ndarray:
+    """``dphi`` wrapped into [-pi, pi), rounded by ``f``."""
+    two_pi = f(2 * np.pi)
+    dphi = np.where(dphi >= f(np.pi), f(dphi - two_pi), dphi)
+    return np.where(dphi < f(-np.pi), f(dphi + two_pi), dphi)
 
 
 def output_branches(doc: dict, cols: Columns) -> list[str]:

@@ -4,8 +4,11 @@ A cell names a configuration and a traffic mix.  The configuration's
 file is the one ``BENCHMARK.json`` gives; the traffic mix is
 ``traffic/<traffic>.json``, each query template it uses is
 ``templates/<template>.json`` and each metric is read by
-``metrics/<metric>.py``.  Nothing here knows any cell, configuration,
-traffic mix or metric by name.
+``metrics/<metric>.py``.  A configuration brings the rest of what it
+needs as new files too: the reference evaluates each selection node by
+``rules/<type>.py`` (``harness/reference.py``), and the generator draws
+the fields its ``store`` declares (``harness/gen.py``).  Nothing here
+knows any cell, configuration, traffic mix, node type or metric by name.
 """
 
 from __future__ import annotations
@@ -42,12 +45,16 @@ class Cell:
     metrics: list = field(default_factory=list)  # every Metric of this cell
 
 
-def _reader(name: str):
-    path = os.path.join(BENCH_DIR, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"skimbench_metric_{name}", path)
+def load_module(path: str, name: str):
+    """The Python file at ``path``, imported as a module named ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def _reader(name: str):
+    return load_module(os.path.join(BENCH_DIR, "metrics", f"{name}.py"), f"skimbench_metric_{name}")
 
 
 def load_cell(workload: str, bench_path: str | None = None) -> Cell:

@@ -9,6 +9,13 @@ decode on the device when the tier is ``device`` and no basket went to
 the host; the compressed plane bytes in are not counted, because the
 program counts the compressed size of the baskets it fetches and not of
 those it decodes, so this share is a lower bound.
+
+The branches a stage reads come from its node type's rule
+(``rules/<type>.py``), so a configuration's new node type is counted
+with no edit here.  A later configuration's own kernel roofline is a new
+``metrics/<name>.py`` that passes its own custom-call pattern to
+``run.device_trace.kernel_s``, as ``kernels.predicate_roofline`` passes
+``kernels.json``'s.
 """
 
 from __future__ import annotations
