@@ -3,8 +3,10 @@
 Builds a NanoAOD-shaped store in-process from a fixed seed (the
 benchmark's shape: 64 trigger bits, 120 filler branches, bitpack codec,
 default baskets; 1,000,000 events unless ``--events`` says otherwise),
-then serves three queries -- the Higgs-style benchmark selection, a
-dielectron invariant-mass window and an electron-jet Delta-R cut --
+then serves four queries -- the Higgs-style benchmark selection, a
+dielectron invariant-mass window, an electron-jet Delta-R cut and the
+ADL benchmark's Query 5 (an opposite-charge dimuon mass window over all
+muon pairs) --
 through ``SkimService(EngineBackend(store))`` on the default executor
 (fused, cascaded), once per window and once with ``device_batch=4``.
 
@@ -63,6 +65,16 @@ DELTA_R_QUERY = {
     },
 }
 
+ADL_Q5_QUERY = {
+    "branches": ["MET_pt", "event", "luminosityBlock"],
+    "selection": {
+        "event": [
+            {"type": "pair_mass", "collection": "Muon", "charge": "opposite",
+             "window": [60.0, 120.0]},
+        ],
+    },
+}
+
 DEVICE_BATCHES = (None, 4)
 SEED = 12  # the benchmark store's generator seed
 
@@ -106,7 +118,8 @@ class CompileClock:
 def queries() -> dict:
     from benchmarks.common import QUERY
 
-    return {"higgs": QUERY, "zee_mass": MASS_QUERY, "e_jet_dr": DELTA_R_QUERY}
+    return {"higgs": QUERY, "zee_mass": MASS_QUERY, "e_jet_dr": DELTA_R_QUERY,
+            "adl_q5": ADL_Q5_QUERY}
 
 
 def build_store(n_events: int, seed: int, decode_backend: str | None = None):

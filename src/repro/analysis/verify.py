@@ -58,11 +58,13 @@ from repro.kernels.ref import (
     GROUP_EXPR,
     GROUP_HT,
     GROUP_MASS,
+    GROUP_PAIR_MASS,
     OP_IDS,
 )
 
 _KNOWN_KINDS = frozenset(
-    (GROUP_COUNT, GROUP_HT, GROUP_ANY, GROUP_MASS, GROUP_DR, GROUP_EXPR)
+    (GROUP_COUNT, GROUP_HT, GROUP_ANY, GROUP_MASS, GROUP_DR, GROUP_EXPR,
+     GROUP_PAIR_MASS)
 )
 _KNOWN_OPS = frozenset(OP_IDS.values())
 _RPN_PUSH = frozenset((RPN_BRANCH, RPN_SUM, RPN_CONST))
@@ -214,6 +216,29 @@ def verify_program(program) -> None:
             if program.group_collections[g] is None or coll2 is None:
                 raise VerifyError(
                     "group-wiring", f"{where}: pair group needs both collections wired"
+                )
+        if grp.kind == GROUP_PAIR_MASS:
+            if len(grp.term_ids) != 5:
+                raise VerifyError(
+                    "group-shape",
+                    f"{where}: all-pairs group wants 5 terms (pt, eta, phi, "
+                    f"mass, charge), has {len(grp.term_ids)}",
+                )
+            if len(grp.ops) > 1 or len(grp.thrs) != len(grp.ops):
+                raise VerifyError(
+                    "group-shape",
+                    f"{where}: all-pairs group takes at most one charge op",
+                )
+            if any(op not in _KNOWN_OPS for op in grp.ops):
+                raise VerifyError("group-opcode", f"{where}: unknown charge op")
+            if program.group_collections[g] is None:
+                raise VerifyError(
+                    "group-wiring", f"{where}: all-pairs group needs its collection"
+                )
+            if not grp.cmp_thr < grp.cmp_thr2:
+                raise VerifyError(
+                    "group-shape",
+                    f"{where}: empty window ({grp.cmp_thr}, {grp.cmp_thr2})",
                 )
         if grp.kind == GROUP_EXPR:
             _check_rpn(where, grp.rpn, n_terms)

@@ -38,6 +38,7 @@ from repro.core.query import (
     HTCut,
     MassWindow,
     ObjectSelection,
+    PairMassWindow,
     Query,
     parse_query,
 )
@@ -70,6 +71,9 @@ def _node_doc(node) -> list:
         # collections (mass and ΔR of (leading A, leading B)), so the
         # canonical form sorts the pair and reordered queries share a key
         return ["mass", sorted(node.collections), float(node.lo), float(node.hi)]
+    if isinstance(node, PairMassWindow):
+        return ["pair_mass", node.collection, node.charge,
+                float(node.lo), float(node.hi)]
     if isinstance(node, DeltaRCut):
         return ["deltaR", sorted(node.collections), node.op, float(node.value)]
     if isinstance(node, ExprCut):

@@ -10,9 +10,10 @@ branches against constants.  This module is the host half of that tier:
     evaluator and the compiled device :class:`~repro.kernels.predicate_eval.Program`
     execute — same post-order, same op sequence, so the two host paths
     are bit-identical by construction;
-  * leading-pair kinematics (invariant mass, ΔR) shared by the query
-    evaluator (``repro.core.query.eval_node``) and the fused program
-    interpreter (``repro.core.neardata.program_eval_np``).
+  * leading-pair kinematics (invariant mass, ΔR) and the all-pairs mass
+    of one collection, shared by the query evaluator
+    (``repro.core.query.eval_node``) and the fused program interpreter
+    (``repro.core.neardata.program_eval_np``).
 
 Everything here is float64 NumPy; the device kernels mirror the same
 formulas in float32 (the HT precedent: bit-identical on the repo
@@ -441,6 +442,35 @@ def wrap_dphi(dphi: np.ndarray) -> np.ndarray:
     return (dphi + np.pi) % (2.0 * np.pi) - np.pi
 
 
+def slot_kinematics(pt, eta, phi, mass):
+    """Per-object terms of the pair mass: ``(px, py, pz, mT², E, m²)``.
+
+    Computed once per object (the transcendental work: exp, cos, sin,
+    sqrt), then combined per pair by :func:`mass_from_kinematics`; the
+    float32 device kernel mirrors both term for term (kernels/ref.py).
+    ``sinh`` is spelled through ``exp``, as the kernel must spell it."""
+    pz = pt * (0.5 * (np.exp(eta) - np.exp(-eta)))
+    mtsq = mass * mass + pt * pt
+    return (pt * np.cos(phi), pt * np.sin(phi), pz, mtsq,
+            np.sqrt(mtsq + pz * pz), mass * mass)
+
+
+def mass_from_kinematics(k1, k2) -> np.ndarray:
+    """Invariant mass of two objects from their :func:`slot_kinematics`.
+
+    Cancellation-free: m² = m1² + m2² + 2(E1E2 − pz1pz2 − pT1·pT2), with
+    E1E2 − pz1pz2 taken as (mT1²mT2² + mT1²pz2² + mT2²pz1²) /
+    (E1E2 + pz1pz2) when pz1pz2 > 0 (two boosted objects, E ≈ |pz|)."""
+    px1, py1, pz1, mtsq1, e1, msq1 = k1
+    px2, py2, pz2, mtsq2, e2, msq2 = k2
+    ee, zz = e1 * e2, pz1 * pz2
+    den = ee + zz
+    boosted = (mtsq1 * mtsq2 + mtsq1 * pz2 * pz2 + mtsq2 * pz1 * pz1) / np.where(den > 0, den, 1.0)
+    long_ = np.where(zz > 0, boosted, ee - zz)
+    m2 = msq1 + msq2 + 2.0 * (long_ - (px1 * px2 + py1 * py2))
+    return np.sqrt(np.maximum(m2, 0.0))
+
+
 def leading_pair_mass(
     data: dict, coll_a: str, coll_b: str
 ) -> tuple[np.ndarray, np.ndarray]:
@@ -453,22 +483,59 @@ def leading_pair_mass(
                                 ("pt", "eta", "phi", "mass"))
 
     def kin(c):
-        pt = c["pt"]
-        pz = pt * (0.5 * (np.exp(c["eta"]) - np.exp(-c["eta"])))
-        mtsq = c["mass"] * c["mass"] + pt * pt
-        return pt * np.cos(c["phi"]), pt * np.sin(c["phi"]), pz, mtsq, np.sqrt(mtsq + pz * pz)
+        return slot_kinematics(c["pt"], c["eta"], c["phi"], c["mass"])
 
-    px1, py1, pz1, mtsq1, e1 = kin(a)
-    px2, py2, pz2, mtsq2, e2 = kin(b)
-    ee, zz = e1 * e2, pz1 * pz2
-    den = ee + zz
-    boosted = (mtsq1 * mtsq2 + mtsq1 * pz2 * pz2 + mtsq2 * pz1 * pz1) / np.where(den > 0, den, 1.0)
-    long_ = np.where(zz > 0, boosted, ee - zz)
-    m2 = (
-        a["mass"] * a["mass"] + b["mass"] * b["mass"]
-        + 2.0 * (long_ - (px1 * px2 + py1 * py2))
+    return mass_from_kinematics(kin(a), kin(b)), ok
+
+
+# ---------------------------------------------------------------------------
+# all-pairs kinematics (the ``pair_mass`` node)
+# ---------------------------------------------------------------------------
+
+#: a pair's charge condition as comparisons of the product q_i·q_j of
+#: its charges with 0 (the staged evaluator applies them by name, the
+#: compiled program as op ids)
+PAIR_CHARGES = {"opposite": ("<",), "same": (">",), "any": ()}
+
+
+def pair_indices(counts: np.ndarray):
+    """Every pair ``i < j`` of objects within an event ->
+    ``(event, first, second)``, the last two global value-array indices.
+
+    Pairs are enumerated in storage order: the ``r``-th pair of an event
+    is ``(i, j)`` with ``r = j(j−1)/2 + i``."""
+    counts = np.asarray(counts, dtype=np.int64)
+    npairs = counts * (counts - 1) // 2
+    ev = np.repeat(np.arange(len(counts)), npairs)
+    r = np.arange(int(npairs.sum()), dtype=np.int64) - np.repeat(
+        np.cumsum(npairs) - npairs, npairs
     )
-    return np.sqrt(np.maximum(m2, 0.0)), ok
+    j = ((1.0 + np.sqrt(1.0 + 8.0 * r)) // 2).astype(np.int64)
+    j -= j * (j - 1) // 2 > r  # float rounding of the root, both ways
+    j += (j + 1) * j // 2 <= r
+    base = (np.cumsum(counts) - counts)[ev]
+    return ev, base + r - j * (j - 1) // 2, base + j
+
+
+def all_pair_masses(data: dict, coll: str):
+    """Invariant mass and charge product of every pair of ``coll``'s
+    objects -> ``(event, m, qq)``, float64; each object's kinematics are
+    computed once (:func:`slot_kinematics`), then gathered per pair."""
+    ev, a, b = pair_indices(data[f"n{coll}"])
+    kin = slot_kinematics(
+        *(np.asarray(data[f"{coll}_{v}"], dtype=np.float64)
+          for v in ("pt", "eta", "phi", "mass"))
+    )
+    m = mass_from_kinematics([k[a] for k in kin], [k[b] for k in kin])
+    q = np.asarray(data[f"{coll}_charge"], dtype=np.float64)
+    return ev, m, q[a] * q[b]
+
+
+def any_pair_in_window(ev, m, ok, lo: float, hi: float, n_events: int) -> np.ndarray:
+    """Events with a pair that ``ok`` admits and whose mass lies in the
+    open window ``lo < m < hi``."""
+    hit = ok & (m > lo) & (m < hi)
+    return np.bincount(ev[hit], minlength=n_events) > 0
 
 
 def leading_delta_r(
@@ -482,7 +549,8 @@ def leading_delta_r(
 
 
 KINEMATIC_VARS = {"mass": ("pt", "eta", "phi", "mass"),
-                  "deltaR": ("pt", "eta", "phi")}
+                  "deltaR": ("pt", "eta", "phi"),
+                  "pair_mass": ("pt", "eta", "phi", "mass", "charge")}
 
 
 __all__ = [
@@ -491,4 +559,6 @@ __all__ = [
     "ExprError", "parse_expr", "to_rpn", "compile_expr", "rpn_branches",
     "validate_rpn", "counts_name", "eval_rpn", "eval_expr_np",
     "leading_pair_mass", "leading_delta_r", "wrap_dphi", "KINEMATIC_VARS",
+    "slot_kinematics", "mass_from_kinematics", "PAIR_CHARGES", "pair_indices",
+    "all_pair_masses", "any_pair_in_window",
 ]

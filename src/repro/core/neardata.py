@@ -237,6 +237,14 @@ def program_eval_np(
                 data, coll, program.group_collections2[g]
             )
             gpass = ok & (m >= grp.cmp_thr) & (m <= grp.cmp_thr2)
+        elif grp.kind == kref.GROUP_PAIR_MASS:
+            ev, m, qq = xpr.all_pair_masses(data, coll)
+            ok = np.ones(len(qq), dtype=bool)
+            for op, thr in zip(grp.ops, grp.thrs):
+                ok &= np.asarray(_NP_OPS[op](qq, thr), dtype=bool)
+            gpass = xpr.any_pair_in_window(
+                ev, m, ok, grp.cmp_thr, grp.cmp_thr2, n_events
+            )
         elif grp.kind == kref.GROUP_DR:
             dr, ok = xpr.leading_delta_r(
                 data, coll, program.group_collections2[g]
@@ -326,6 +334,31 @@ def window_pad_K(data: dict[str, np.ndarray], program: Program, store) -> int:
 _WINDOW_QUANTUM = 512  # event-axis padding multiple (fused kernel tile)
 
 
+def pair_work(program: Program, counts_of, events: int, K: int) -> dict:
+    """Span attributes of a launch that evaluates ``program``'s pair
+    groups over ``events`` event rows of capacity ``K``: ``pair_slots``,
+    the K(K−1)/2 slot pairs of every row, padding included, and
+    ``pairs_live``, the n(n−1)/2 pairs of real objects, from each pair
+    group's counts branch (``counts_of(name)`` -> the real events'
+    counts).  Empty for a program without pair groups, so other launches
+    carry no such attributes."""
+    colls = [
+        program.group_collections[g]
+        for g, grp in enumerate(program.groups)
+        if grp.kind == kref.GROUP_PAIR_MASS
+    ]
+    if not colls:
+        return {}
+    live = 0
+    for coll in colls:
+        n = np.asarray(counts_of(f"n{coll}"), dtype=np.int64)
+        live += int((n * (n - 1) // 2).sum())
+    return {
+        "pair_slots": len(colls) * events * (K * (K - 1) // 2),
+        "pairs_live": live,
+    }
+
+
 def default_fused_backend() -> str:
     """The fused evaluator a run uses when none is forced: the Pallas
     kernels on TPU, the compiled-program host interpreter elsewhere."""
@@ -379,8 +412,9 @@ def fused_window_skim(
 
     ``tracer`` records the device backends' host–device boundary: the
     densification (``stage_inputs``), the uploads and kernel enqueue
-    (``device_launch``) and the one blocking read-back of the compacted
-    rows and their count (``device_wait``).
+    (``device_launch``, with :func:`pair_work`'s counts for pair groups)
+    and the one blocking read-back of the compacted rows and their count
+    (``device_wait``).
     """
     flat = next(
         n for n in data if not (store.branches.get(n) and store.branches[n].jagged)
@@ -437,6 +471,8 @@ def fused_window_skim(
         if tr.enabled:
             sp["op"] = "fused_skim"
             sp["h2d_bytes"] = sum(a.nbytes for a in host)
+            for key, value in pair_work(program, data.__getitem__, target, K).items():
+                sp[key] = value
     with tr.span("device_wait", kind="device_wait") as sp:
         # slice on the host: a device slice per survivor count would
         # compile a new program for every distinct count
@@ -503,5 +539,6 @@ __all__ = [
     "fused_window_skim",
     "default_fused_backend",
     "window_pad_K",
+    "pair_work",
     "sharded_skim",
 ]

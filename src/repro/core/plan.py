@@ -51,6 +51,7 @@ from repro.core.query import (
     Cut,
     HTCut,
     ObjectSelection,
+    PairMassWindow,
     Query,
 )
 from repro.core.zonemap import ACCEPT_ALL, PRUNE, SCAN
@@ -94,6 +95,7 @@ _NODE_KIND = {
     "MassWindow": "mass",
     "DeltaRCut": "deltaR",
     "ExprCut": "expr",
+    "PairMassWindow": "pair_mass",
 }
 
 
@@ -240,6 +242,12 @@ def estimate_node_selectivity(node, stats_of, store) -> float:
         return _poisson_tail(mean_count * p_obj, node.min_count)
     if isinstance(node, HTCut):
         return DEFAULT_SELECTIVITY
+    if isinstance(node, PairMassWindow):
+        # an event needs two objects; the window and charge are a guess
+        st = stats_of(f"{node.collection}_pt")
+        if st is None or not st.n_entries:
+            return DEFAULT_SELECTIVITY
+        return _poisson_tail(st.n_values / st.n_entries, 2) * DEFAULT_SELECTIVITY
     return DEFAULT_SELECTIVITY  # mass / ΔR / expr: stats say nothing
 
 
@@ -987,6 +995,16 @@ class CascadeExecutor:
                 if tr.enabled:
                     sp["op"] = "cascade_stage"
                     sp["h2d_bytes"] = terms.nbytes + valid.nbytes + weights.nbytes
+                    spans_data = [d for b in alive for _o, _n, d in staged[b]]
+                    work = nd.pair_work(
+                        stage.program,
+                        lambda name: np.concatenate(
+                            [np.asarray(d[name]) for d in spans_data] or [[]]
+                        ),
+                        Bn * pad_E, K_b,
+                    )
+                    for key, value in work.items():
+                        sp[key] = value
             with tr.span("device_wait", kind="device_wait") as sp:
                 basket_host = np.asarray(basket_dev)
                 counts_host_new = np.asarray(counts_dev)

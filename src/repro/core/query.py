@@ -26,6 +26,8 @@ declarative JSON document::
           {"type": "cut", "branch": "MET_pt", "op": ">", "value": 40.0},
           {"type": "mass", "collections": ["Electron", "Electron"],
            "window": [80.0, 100.0]},
+          {"type": "pair_mass", "collection": "Muon", "charge": "opposite",
+           "window": [60.0, 120.0]},
           {"type": "deltaR", "collections": ["Electron", "Jet"],
            "op": ">", "value": 0.4},
           {"type": "expr", "expr": "MET_pt + 0.5*sum(Jet_pt)",
@@ -38,8 +40,9 @@ The three selection tiers map to the paper's hierarchical model:
 *preselection* (cheap single-branch cuts), *object-level* (per-particle
 kinematic cuts over jagged collections), *event-level* (composite derived
 variables such as HT, trigger ORs, and the derived-kinematics tier:
-leading-pair invariant-mass windows, ΔR, and arithmetic expressions over
-flat branches and ``sum()`` reductions — DESIGN.md §10).  Stages run in
+leading-pair invariant-mass windows, ΔR, all-pairs mass windows with a
+charge condition, and arithmetic expressions over flat branches and
+``sum()`` reductions — DESIGN.md §10, §17).  Stages run in
 order and events are discarded as early as possible (basket-granular
 short-circuiting in the engine).
 
@@ -57,6 +60,9 @@ import numpy as np
 
 from repro.core.expr import (
     KINEMATIC_VARS,
+    PAIR_CHARGES,
+    all_pair_masses,
+    any_pair_in_window,
     compile_expr,
     eval_expr_np,
     leading_delta_r,
@@ -166,6 +172,24 @@ class MassWindow:
 
 
 @dataclass(frozen=True)
+class PairMassWindow:
+    """Event tier: some pair of two distinct objects of one collection,
+    taken from all pairs, meets the ``charge`` condition on the product
+    of its charges (``"opposite"``: q_i·q_j < 0, ``"same"``: > 0,
+    ``"any"``) and has an invariant mass in the open window
+    ``lo < m < hi`` (the ADL benchmark's Query 5 shape)."""
+
+    collection: str
+    charge: str
+    lo: float
+    hi: float
+
+    def branches(self) -> set[str]:
+        c = self.collection
+        return {f"n{c}"} | {f"{c}_{v}" for v in KINEMATIC_VARS["pair_mass"]}
+
+
+@dataclass(frozen=True)
 class DeltaRCut:
     """Event tier: ΔR between the leading pair, compared to a threshold.
 
@@ -261,6 +285,24 @@ def _parse_varcuts(items) -> tuple[VarCut, ...]:
     return tuple(VarCut(c["var"], c["op"], c["value"]) for c in items)
 
 
+def _parse_pair_mass(e: dict) -> PairMassWindow:
+    coll = e.get("collection")
+    if not isinstance(coll, str) or not coll:
+        raise ValueError("pair_mass node needs one collection name")
+    charge = e.get("charge", "any")
+    if charge not in PAIR_CHARGES:
+        raise ValueError(
+            f"pair_mass charge {charge!r}: one of {sorted(PAIR_CHARGES)}"
+        )
+    window = e.get("window")
+    if window is None or len(window) != 2:
+        raise ValueError("pair_mass node needs a window [lo, hi]")
+    lo, hi = float(window[0]), float(window[1])
+    if not lo < hi:
+        raise ValueError(f"pair_mass window [{lo}, {hi}] is empty")
+    return PairMassWindow(coll, charge, lo, hi)
+
+
 def parse_query(doc: dict | str, strict: bool = False) -> Query:
     """Parse a JSON query document (dict or JSON string) into a :class:`Query`.
 
@@ -304,6 +346,8 @@ def parse_query(doc: dict | str, strict: bool = False) -> Query:
                 raise ValueError("mass node needs exactly two collections")
             lo, hi = e["window"]
             events.append(MassWindow(colls, float(lo), float(hi)))
+        elif kind == "pair_mass":
+            events.append(_parse_pair_mass(e))
         elif kind == "deltaR":
             colls = tuple(e["collections"])
             if len(colls) != 2:
@@ -372,6 +416,13 @@ def eval_node(node, data: dict, n_events: int | None = None) -> np.ndarray:
     if isinstance(node, MassWindow):
         m, ok = leading_pair_mass(data, *node.collections)
         return ok & (m >= node.lo) & (m <= node.hi)
+    if isinstance(node, PairMassWindow):
+        ev, m, qq = all_pair_masses(data, node.collection)
+        ok = np.ones(len(qq), dtype=bool)
+        for op in PAIR_CHARGES[node.charge]:
+            ok &= OPS[op](qq, 0.0)
+        n = len(data[f"n{node.collection}"])
+        return any_pair_in_window(ev, m, ok, node.lo, node.hi, n)
     if isinstance(node, DeltaRCut):
         dr, ok = leading_delta_r(data, *node.collections)
         return ok & np.asarray(OPS[node.op](dr, node.value), dtype=bool)

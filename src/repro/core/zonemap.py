@@ -71,6 +71,7 @@ from repro.core.query import (
     HTCut,
     MassWindow,
     ObjectSelection,
+    PairMassWindow,
     Query,
 )
 
@@ -387,9 +388,9 @@ def classify_node(node, stats_of, store) -> int:
         return _classify_ht(node, stats_of, store)
     if isinstance(node, ExprCut):
         return _classify_expr(node, stats_of, store)
-    if isinstance(node, (MassWindow, DeltaRCut)):
-        # nonlinear leading-pair kinematics: window bounds on pt/eta/phi
-        # do not bound the pair observable tightly enough to skip safely
+    if isinstance(node, (MassWindow, DeltaRCut, PairMassWindow)):
+        # nonlinear pair kinematics: window bounds on pt/eta/phi do not
+        # bound the pair observable tightly enough to skip safely
         return MAYBE
     return MAYBE  # unknown node types never authorize a skip
 

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.expr import KINEMATIC_VARS, RPN_BRANCH, RPN_SUM
+from repro.core.expr import KINEMATIC_VARS, PAIR_CHARGES, RPN_BRANCH, RPN_SUM
 from repro.kernels.ref import (
     GROUP_ANY,
     GROUP_COUNT,
@@ -29,6 +29,7 @@ from repro.kernels.ref import (
     GROUP_EXPR,
     GROUP_HT,
     GROUP_MASS,
+    GROUP_PAIR_MASS,
     OP_IDS,
     predicate_mask,
 )
@@ -40,19 +41,34 @@ EVENT_TILE = 1024  # events per grid step; multiple of 8*128 lanes
 # blocks of one grid step (T term planes + G validity + G weight planes,
 # double-buffered) are held under _BLOCK_BUDGET by shrinking the event
 # tile, and the scoped limit leaves room for the body's (tile, K)
-# temporaries (the mass group keeps a few dozen alive).
+# temporaries (the mass group keeps a few dozen alive).  The all-pairs
+# group holds more: its eight per-slot planes (six kinematic terms,
+# charge, validity), their rolled copies and the pair terms of one roll
+# step, PAIR_TEMPS planes in all, which _TEMP_BUDGET bounds.
 _BLOCK_BUDGET = 4 << 20
+_TEMP_BUDGET = 16 << 20
+PAIR_TEMPS = 40
 _MIN_TILE = 128  # mask blocks must stay a multiple of 128 lanes
 COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 << 20)
 
 
-def fit_event_tile(event_tile: int, planes: int, K: int) -> int:
+def fit_event_tile(event_tile: int, planes: int, K: int, temps: int = 0) -> int:
     """Largest tile <= ``event_tile`` (halving) whose double-buffered
-    input blocks of ``planes`` (tile, K) float planes fit the budget."""
+    input blocks of ``planes`` (tile, K) float planes fit the block
+    budget and whose ``temps`` body temporaries of the same shape fit the
+    temporaries budget."""
     row_bytes = 4 * 128 * -(-K // 128)
-    while event_tile > _MIN_TILE and 2 * planes * event_tile * row_bytes > _BLOCK_BUDGET:
+    while event_tile > _MIN_TILE and (
+        2 * planes * event_tile * row_bytes > _BLOCK_BUDGET
+        or temps * event_tile * row_bytes > _TEMP_BUDGET
+    ):
         event_tile //= 2
     return event_tile
+
+
+def pair_temps(program) -> int:
+    """(tile, K) temporaries the body of ``program``'s pair groups holds."""
+    return PAIR_TEMPS * sum(g.kind == GROUP_PAIR_MASS for g in program.groups)
 
 
 @dataclass(frozen=True)
@@ -64,7 +80,7 @@ class Group:
     min_count: int = 1
     cmp_op: int = 0
     cmp_thr: float = 0.0
-    cmp_thr2: float = 0.0  # mass window upper bound (GROUP_MASS)
+    cmp_thr2: float = 0.0  # mass window upper bound (GROUP_MASS, GROUP_PAIR_MASS)
     rpn: tuple = ()  # GROUP_EXPR stack program, term-slot operands
 
 
@@ -105,6 +121,7 @@ def compile_query(query) -> Program:
         HTCut,
         MassWindow,
         ObjectSelection,
+        PairMassWindow,
     )
 
     term_branches: list[str] = []
@@ -186,6 +203,21 @@ def compile_query(query) -> Program:
                         cmp_thr=float(node.lo), cmp_thr2=float(node.hi),
                     ),
                     coll=a, coll2=b,
+                )
+            elif isinstance(node, PairMassWindow):
+                # terms pt, eta, phi, mass, charge of one collection; the
+                # charge condition is a comparison of q_i·q_j with 0
+                ids = tuple(
+                    add_term(f"{node.collection}_{v}")
+                    for v in KINEMATIC_VARS["pair_mass"]
+                )
+                ops = tuple(OP_IDS[o] for o in PAIR_CHARGES[node.charge])
+                add_group(
+                    Group(
+                        GROUP_PAIR_MASS, ids, ops, (0.0,) * len(ops),
+                        cmp_thr=float(node.lo), cmp_thr2=float(node.hi),
+                    ),
+                    coll=node.collection,
                 )
             elif isinstance(node, DeltaRCut):
                 a, b = node.collections
@@ -284,7 +316,7 @@ def predicate_eval_batch(
     """
     Bn, T, E, K = terms.shape
     G = valid.shape[1]
-    event_tile = fit_event_tile(event_tile, T + 2 * G, K)
+    event_tile = fit_event_tile(event_tile, T + 2 * G, K, pair_temps(program))
     assert E % event_tile == 0, (E, event_tile)
     grid = (Bn, E // event_tile)
 
@@ -324,7 +356,7 @@ def predicate_eval(
     """
     T, E, K = terms.shape
     G = valid.shape[0]
-    event_tile = fit_event_tile(event_tile, T + 2 * G, K)
+    event_tile = fit_event_tile(event_tile, T + 2 * G, K, pair_temps(program))
     assert E % event_tile == 0, (E, event_tile)
     grid = (E // event_tile,)
 

@@ -47,6 +47,7 @@ GROUP_ANY = 2  # OR over terms (flat boolean branches)
 GROUP_MASS = 3  # leading-pair invariant mass inside [cmp_thr, cmp_thr2]
 GROUP_DR = 4  # leading-pair ΔR cmp threshold
 GROUP_EXPR = 5  # arithmetic stack program (Group.rpn) cmp threshold
+GROUP_PAIR_MASS = 6  # some slot pair's mass in (cmp_thr, cmp_thr2), charge ops
 
 
 def apply_op(x, op_id: int, thr: float):
@@ -107,31 +108,42 @@ def _unpack_validity(vg: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     return jnp.mod(vg, 2.0) >= 1.0, vg >= 2.0
 
 
-def _pair_mass(a, b):
-    """Invariant mass of two objects given as (pt, eta, phi, mass) —
-    mirrored term-for-term by the float64 host helper
-    (repro.core.expr.leading_pair_mass).
+def _slot_kin(pt, eta, phi, mass):
+    """Per-slot terms of the pair mass, ``(px, py, pz, mT², E, m²)`` —
+    all of the transcendental work (exp, cos, sin, sqrt), done once per
+    slot; mirrored term-for-term by the float64 host helper
+    (repro.core.expr.slot_kinematics).  sinh is spelled through ``exp``,
+    which the TPU kernel compiler lowers."""
+    pz = pt * (0.5 * (jnp.exp(eta) - jnp.exp(-eta)))
+    mtsq = mass * mass + pt * pt
+    return (pt * jnp.cos(phi), pt * jnp.sin(phi), pz, mtsq,
+            jnp.sqrt(mtsq + pz * pz), mass * mass)
+
+
+def _kin_mass(k1, k2):
+    """Invariant mass of two objects from their :func:`_slot_kin` terms.
 
     Written without catastrophic cancellation, so the float32 result
     stays within a few ulp of float64:
     m² = m1² + m2² + 2(E1E2 − pz1pz2 − pT1·pT2), with E1E2 − pz1pz2
     taken as (mT1²mT2² + mT1²pz2² + mT2²pz1²) / (E1E2 + pz1pz2) when
     pz1pz2 > 0 (two boosted objects, E ≈ |pz|).  The textbook
-    (E1+E2)² − |p1+p2|² loses ~4 digits there.  sinh is spelled
-    through ``exp``, which the TPU kernel compiler lowers."""
-    def kin(pt, eta, phi, mass):
-        pz = pt * (0.5 * (jnp.exp(eta) - jnp.exp(-eta)))
-        mtsq = mass * mass + pt * pt
-        return pt * jnp.cos(phi), pt * jnp.sin(phi), pz, mtsq, jnp.sqrt(mtsq + pz * pz)
-
-    px1, py1, pz1, mtsq1, e1 = kin(*a)
-    px2, py2, pz2, mtsq2, e2 = kin(*b)
+    (E1+E2)² − |p1+p2|² loses ~4 digits there."""
+    px1, py1, pz1, mtsq1, e1, msq1 = k1
+    px2, py2, pz2, mtsq2, e2, msq2 = k2
     ee, zz = e1 * e2, pz1 * pz2
     den = ee + zz
     boosted = (mtsq1 * mtsq2 + mtsq1 * pz2 * pz2 + mtsq2 * pz1 * pz1) / jnp.where(den > 0, den, 1.0)
     long_ = jnp.where(zz > 0, boosted, ee - zz)
-    m2 = a[3] * a[3] + b[3] * b[3] + 2.0 * (long_ - (px1 * px2 + py1 * py2))
+    m2 = msq1 + msq2 + 2.0 * (long_ - (px1 * px2 + py1 * py2))
     return jnp.sqrt(jnp.maximum(m2, 0.0))
+
+
+def _pair_mass(a, b):
+    """Invariant mass of two objects given as (pt, eta, phi, mass) —
+    mirrored by the float64 host helper
+    (repro.core.expr.leading_pair_mass)."""
+    return _kin_mass(_slot_kin(*a), _slot_kin(*b))
 
 
 def _group_mass(grp, terms, vg, same: bool):
@@ -156,6 +168,33 @@ def _group_dr(grp, terms, vg, same: bool):
     ) - pi
     dr = jnp.sqrt(deta * deta + dphi * dphi)
     return ok & apply_op(dr, grp.cmp_op, grp.cmp_thr)
+
+
+def _group_pair_mass(grp, terms, live):
+    """All-pairs mass window over one collection's (E, K) slots.
+
+    Each slot's kinematics are computed once, as (E, K) planes.  A pair
+    of slots {i, j} lies at cyclic lane distance s = min(|i−j|,
+    K−|i−j|) in 1..K/2, so K/2 steps that roll every plane by s along
+    the lane axis pair each slot i with slot i+s (mod K) and evaluate
+    every pair at that distance at once; the last step (s = K/2) meets
+    each of its pairs twice, which the OR below absorbs.  A pair counts
+    when both slots are live, the charge product q_i·q_j passes every
+    (op, thr) of ``grp.ops``/``grp.thrs`` and lo < m < hi (open)."""
+    ids = grp.term_ids  # (pt, eta, phi, mass, charge)
+    kin = _slot_kin(*(terms[i] for i in ids[:4]))
+    q = terms[ids[4]]
+    planes = (*kin, q, live)
+    K = live.shape[-1]
+    hit = jnp.zeros(live.shape, dtype=bool)
+    for s in range(1, K // 2 + 1):
+        other = [jnp.roll(x, s, axis=-1) for x in planes]
+        m = _kin_mass(kin, other[:6])
+        ok = (live > 0) & (other[7] > 0) & (m > grp.cmp_thr) & (m < grp.cmp_thr2)
+        for op, thr in zip(grp.ops, grp.thrs):
+            ok = ok & apply_op(q * other[6], op, thr)
+        hit = hit | ok
+    return hit.astype(jnp.int32).sum(axis=-1) > 0
 
 
 def _group_expr(grp, terms):
@@ -224,6 +263,8 @@ def predicate_mask(program, terms, valid, weights) -> jnp.ndarray:
             same = program.group_collections[g] == _coll2(program, g)
             fn = _group_mass if grp.kind == GROUP_MASS else _group_dr
             gpass = fn(grp, terms, valid[g], same)
+        elif grp.kind == GROUP_PAIR_MASS:
+            gpass = _group_pair_mass(grp, terms, valid[g])
         else:
             obj = jnp.ones(terms.shape[1:], dtype=bool)  # (E, K)
             for t, op, thr in zip(grp.term_ids, grp.ops, grp.thrs):

@@ -28,7 +28,12 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.predicate_eval import COMPILER_PARAMS, Program, fit_event_tile
+from repro.kernels.predicate_eval import (
+    COMPILER_PARAMS,
+    Program,
+    fit_event_tile,
+    pair_temps,
+)
 from repro.kernels.ref import predicate_mask
 
 EVENT_TILE = 512
@@ -131,7 +136,7 @@ def skim_fused_batch(terms, valid, weights, payload, *, program: Program,
     Bn, T, E, K = terms.shape
     G = valid.shape[1]
     D = payload.shape[2]
-    event_tile = fit_event_tile(event_tile, T + 2 * G, K)
+    event_tile = fit_event_tile(event_tile, T + 2 * G, K, pair_temps(program))
     assert E % event_tile == 0
     n_tiles = E // event_tile
 
@@ -166,7 +171,7 @@ def skim_fused(terms, valid, weights, payload, *, program: Program,
     T, E, K = terms.shape
     G = valid.shape[0]
     D = payload.shape[1]
-    event_tile = fit_event_tile(event_tile, T + 2 * G, K)
+    event_tile = fit_event_tile(event_tile, T + 2 * G, K, pair_temps(program))
     assert E % event_tile == 0
     n_tiles = E // event_tile
 

@@ -41,7 +41,8 @@ work happens (attrs are filled only when the tracer is enabled):
 ``decode_prep`` (basket parsing and plane padding before a device
 decode, attrs: baskets), ``stage_inputs`` (host densification for a
 predicate or cascade kernel, attrs: events/K), ``device_launch`` (the
-host→device uploads plus the kernel enqueue, attrs: op/h2d_bytes) and
+host→device uploads plus the kernel enqueue, attrs: op/h2d_bytes, and
+for a predicate program with all-pairs groups pair_slots/pairs_live) and
 ``device_wait`` (one blocking read-back of device results to the host,
 attrs: op/d2h_bytes/arrays).  See DESIGN.md §13–14, §16.
 

@@ -6,7 +6,8 @@ would raise -- tiling rules, unlowerable primitives, VMEM overflow.
 Shapes are those ``chip_smoke.py`` drives: 4096-event windows (one
 default basket), batches of 4 windows, object capacity K=16 for
 programs reading jets and K=8 for electron-only ones, 4096-value
-baskets with up to 32 bit-planes.
+baskets with up to 32 bit-planes.  The ADL Query 5 all-pairs group
+compiles at both capacities its windows take, K=8 and K=16.
 
 The topology is described inside a fixture, never at import time: only
 the worker that runs this file loads the TPU compiler.
@@ -31,7 +32,7 @@ from repro.kernels import skim_fused as sf  # noqa: E402
 
 E, BATCH, D, WORDS = 4096, 4, 8, 128
 # (query name in chip_smoke.queries(), object capacity K)
-PROGRAMS = [("higgs", 16), ("zee_mass", 8), ("e_jet_dr", 16)]
+PROGRAMS = [("higgs", 16), ("zee_mass", 8), ("e_jet_dr", 16), ("adl_q5", 8), ("adl_q5", 16)]
 
 
 @pytest.fixture(scope="module")
