@@ -345,20 +345,6 @@ def _window_phase2(
     return cols, jagged
 
 
-def _concat_output(out_cols: dict, n_passed: int, plan: SkimPlan, store) -> dict:
-    """Concatenate per-window survivor columns (empty-output dtype fallback
-    included)."""
-    if n_passed:
-        return {
-            k2: np.concatenate(v) if v else np.empty(0)
-            for k2, v in out_cols.items()
-        }
-    return {
-        k2: np.empty(0, dtype=store.branches[k2].np_dtype())
-        for k2 in plan.output_branches
-    }
-
-
 def _rows_materialize(data: dict[str, np.ndarray], store, n: int) -> list:
     """Legacy deserialization: per-event row objects (the C++-object analogue).
 
@@ -405,12 +391,23 @@ def _select_columns(
 
 
 def _write_output(
-    cols: dict, jagged: dict, store: EventStore, breakdown: Breakdown
+    store: EventStore,
+    pieces: list,
+    names: list[str],
+    jagged: dict,
+    breakdown: Breakdown,
+    tracer=NULL_TRACER,
+    **attrs,
 ) -> EventStore:
+    """Build the job's output store from its windows' survivors, under a
+    ``write`` span: ``pieces`` lists ``(start, stop, n_passed, cols)`` per
+    window with survivors.  Output baskets that are exactly one wholly
+    kept input basket are cloned, the rest encoded
+    (:meth:`EventStore.from_survivors`); the span counts both."""
+    osid = tracer.begin("write", kind="write", **attrs)
     with _Timer(breakdown, "write"):
-        out = EventStore.from_arrays(
-            cols, jagged=jagged, basket_events=store.basket_events, codec=store.codec
-        )
+        out, cloned, encoded = EventStore.from_survivors(store, pieces, names, jagged)
+    tracer.end(osid, baskets_cloned=cloned, baskets_encoded=encoded)
     return out
 
 
@@ -620,13 +617,14 @@ class SkimEngine:
             del rows
 
         cols, jagged = _select_columns(data, mask, store)
-        out = _write_output(cols, jagged, store, b)
+        k = int(mask.sum())
+        out = _write_output(store, [(0, n, k, cols)], list(cols), jagged, b)
 
         b.fetch = self.input_link.transfer_time(stats.bytes_fetched, stats.requests)
         b.output_transfer = 0.0  # filtering ran at the client already
         compute = b.decompress + b.deserialize + b.filter + b.write
         return SkimResult(
-            "client_plain", out, n, int(mask.sum()), b, stats, plan,
+            "client_plain", out, n, k, b, stats, plan,
             busy_fraction=compute / max(b.total(), 1e-12),
         )
 
@@ -659,7 +657,9 @@ class SkimEngine:
         if plan_t is not None:
             tracer.add_span("plan", kind="plan", t0=plan_t[0], t1=plan_t[1])
 
-        out_cols: dict[str, list] = {k: [] for k in plan.output_branches}
+        # (start, stop, n_passed, survivor columns) of every window with
+        # survivors: what the output store is built from at the end
+        out_pieces: list[tuple] = []
         jagged_map: dict[str, str] = {}
         n_passed = 0
         phase2_stats = FetchStats()
@@ -964,8 +964,7 @@ class SkimEngine:
                     )
                 tracer.end(p2sid, bytes=w2s.bytes_fetched)
                 jagged_map.update(jagged)
-                for k2, v in cols.items():
-                    out_cols[k2].append(v)
+                out_pieces.append((start, stop, k, cols))
                 part_cols, part_jagged = cols, jagged
             if outcome is not None:
                 # savings vs the preloading reference, ledgered AFTER both
@@ -1011,11 +1010,9 @@ class SkimEngine:
         phase1_bytes = stats.bytes_fetched  # pre-merge: phase-1 only
         stats.merge(phase2_stats)
 
-        osid = tracer.begin("write", kind="write")
-        with _Timer(b, "write"):
-            cat = _concat_output(out_cols, n_passed, plan, store)
-        out = _write_output(cat, jagged_map, store, b)
-        tracer.end(osid)
+        out = _write_output(
+            store, out_pieces, plan.output_branches, jagged_map, b, tracer
+        )
 
         b.fetch = link.transfer_time(stats.bytes_fetched, stats.requests)
         out_bytes = out.compressed_bytes()

@@ -22,6 +22,7 @@ fetch+decode of window *i+1* with filtering of window *i* (DESIGN.md §4b).
 
 from __future__ import annotations
 
+import bisect
 import io
 import json
 import threading
@@ -384,21 +385,23 @@ class EventStore:
             store._add_jagged(name, values, counts, counts_name)
         return store
 
+    def _encode(self, chunk: np.ndarray, first_entry: int, n_entries: int):
+        """Encode one basket of values: ``(BasketMeta, blob)``."""
+        blob = encode_basket(chunk, self.codec)
+        vmin, vmax, n_true = basket_stats(chunk)
+        meta = BasketMeta(
+            first_entry, n_entries, len(chunk), len(blob), chunk.nbytes,
+            vmin=vmin, vmax=vmax, n_true=n_true, digest=basket_digest(blob),
+        )
+        return meta, blob
+
     def _add_flat(self, name: str, arr: np.ndarray) -> None:
         br = Branch(name, str(arr.dtype), jagged=False)
         metas, blobs = [], []
         for start in range(0, self.n_events, self.basket_events):
             stop = min(start + self.basket_events, self.n_events)
-            chunk = arr[start:stop]
-            blob = encode_basket(chunk, self.codec)
-            vmin, vmax, n_true = basket_stats(chunk)
-            metas.append(
-                BasketMeta(
-                    start, stop - start, len(chunk), len(blob), chunk.nbytes,
-                    vmin=vmin, vmax=vmax, n_true=n_true,
-                    digest=basket_digest(blob),
-                )
-            )
+            meta, blob = self._encode(arr[start:stop], start, stop - start)
+            metas.append(meta)
             blobs.append(blob)
         self.branches[name] = br
         self._baskets[name] = metas
@@ -412,17 +415,9 @@ class EventStore:
         metas, blobs = [], []
         for start in range(0, self.n_events, self.basket_events):
             stop = min(start + self.basket_events, self.n_events)
-            v0, v1 = offsets[start], offsets[stop]
-            chunk = values[v0:v1]
-            blob = encode_basket(chunk, self.codec)
-            vmin, vmax, n_true = basket_stats(chunk)
-            metas.append(
-                BasketMeta(
-                    start, stop - start, len(chunk), len(blob), chunk.nbytes,
-                    vmin=vmin, vmax=vmax, n_true=n_true,
-                    digest=basket_digest(blob),
-                )
-            )
+            chunk = values[offsets[start] : offsets[stop]]
+            meta, blob = self._encode(chunk, start, stop - start)
+            metas.append(meta)
             blobs.append(blob)
         self.branches[name] = br
         self._baskets[name] = metas
@@ -914,16 +909,123 @@ class EventStore:
                 ]
         return store
 
-    # -- mutation used by the skim writer ------------------------------------
+    # -- the skim writer -----------------------------------------------------
 
     @classmethod
-    def from_selection(
+    def from_survivors(
         cls,
-        columns: dict[str, np.ndarray],
+        source: "EventStore",
+        pieces: "list[tuple[int, int, int, dict]]",
+        names: list[str],
         jagged: dict[str, str],
-        basket_events: int,
-        codec: str,
-    ) -> "EventStore":
-        return cls.from_arrays(
-            columns, jagged=jagged, basket_events=basket_events, codec=codec
-        )
+    ) -> "tuple[EventStore, int, int]":
+        """Build a skim's output store from its per-window survivors.
+
+        ``pieces`` lists ``(start, stop, n_passed, cols)`` in window order
+        for every window with survivors: the input event range the window
+        covered and its survivor columns of ``names`` (``jagged`` maps a
+        jagged branch to its counts branch).  The result equals
+        :meth:`from_arrays` on the concatenated survivors with
+        ``source``'s ``basket_events`` and ``codec``, field for field.
+
+        Output baskets are cut every ``basket_events`` survivors, as
+        :meth:`from_arrays` cuts them.  One whose events are exactly one
+        input basket's — that basket's whole event range survived, and
+        its output offset is a multiple of ``basket_events`` — is
+        *cloned*: it takes the input blob and meta, with only
+        ``first_entry`` moved, because encoding the same values again
+        gives the same bytes.  The blob is cloned only after its CRC-32
+        matches the meta's digest (phase 2 may have been served from the
+        decode LRU without re-reading it); otherwise, and for every other
+        basket, the survivor slice is encoded.  Only the survivors of
+        encoded baskets are gathered.  Returns ``(store, baskets_cloned,
+        baskets_encoded)`` (DESIGN.md §18).
+        """
+        bev = source.basket_events
+        n_out = sum(k for _, _, k, _ in pieces)
+        if not n_out:
+            empty = {n: np.empty(0, dtype=source.branches[n].np_dtype()) for n in names}
+            out = cls.from_arrays(empty, jagged=jagged, basket_events=bev, codec=source.codec)
+            return out, 0, 0
+        offsets = [0]  # output offset of each piece, then the total
+        for _, _, k, _ in pieces:
+            offsets.append(offsets[-1] + k)
+        # runs of wholly kept windows, contiguous in input and output:
+        # [input start, input stop, output start]
+        runs: list[list[int]] = []
+        for (start, stop, k, _), o in zip(pieces, offsets):
+            if k != stop - start:
+                continue
+            if runs and runs[-1][1] == start and runs[-1][2] + start - runs[-1][0] == o:
+                runs[-1][1] = stop
+            else:
+                runs.append([start, stop, o])
+        clones: dict[int, int] = {}  # output basket -> input basket
+        for a, b, o in runs:
+            for first in range(-(-a // bev) * bev, b, bev):
+                if min(first + bev, source.n_events) > b:
+                    break
+                if (o + first - a) % bev == 0:
+                    clones[(o + first - a) // bev] = first // bev
+
+        value_offsets: dict[tuple[int, str], np.ndarray] = {}
+
+        def gather(name: str, counts_name: str | None, a: int, b: int, dtype):
+            """Survivor values of output events ``[a, b)``."""
+            parts = []
+            p = bisect.bisect_right(offsets, a) - 1
+            while p < len(pieces) and offsets[p] < b:
+                cols = pieces[p][3]
+                lo, hi = max(a - offsets[p], 0), min(b, offsets[p + 1]) - offsets[p]
+                if counts_name is not None:
+                    off = value_offsets.get((p, counts_name))
+                    if off is None:
+                        off = np.concatenate([[0], np.cumsum(cols[counts_name], dtype=np.int64)])
+                        value_offsets[(p, counts_name)] = off
+                    lo, hi = off[lo], off[hi]
+                parts.append(cols[name][lo:hi])
+                p += 1
+            chunk = parts[0] if len(parts) == 1 else np.concatenate(parts)
+            return chunk.astype(dtype, copy=False)
+
+        out = cls(basket_events=bev, codec=source.codec)
+        out.n_events = n_out
+        n_cloned = n_encoded = 0
+        for name in [n for n in names if n not in jagged] + list(jagged):
+            counts_name = jagged.get(name)
+            dtype = np.result_type(*(cols[name].dtype for _, _, _, cols in pieces))
+            src = source.branches.get(name)
+            clonable = (
+                src is not None
+                and src.np_dtype() == dtype
+                and src.counts_branch == counts_name
+            )
+            metas, blobs = [], []
+            for first in range(0, n_out, bev):
+                n_entries = min(bev, n_out - first)
+                j = clones.get(first // bev) if clonable else None
+                if j is not None:
+                    m, blob = source._baskets[name][j], source._blobs[name][j]
+                    if m.digest is not None and basket_digest(blob) == m.digest:
+                        metas.append(
+                            BasketMeta(
+                                first, m.n_entries, m.n_values, m.comp_bytes,
+                                m.raw_bytes, vmin=m.vmin, vmax=m.vmax,
+                                n_true=m.n_true, digest=m.digest,
+                            )
+                        )
+                        blobs.append(blob)
+                        n_cloned += 1
+                        continue
+                chunk = gather(name, counts_name, first, first + n_entries, dtype)
+                meta, blob = out._encode(chunk, first, n_entries)
+                metas.append(meta)
+                blobs.append(blob)
+                n_encoded += 1
+            out.branches[name] = Branch(
+                name, str(dtype), jagged=counts_name is not None,
+                counts_branch=counts_name,
+            )
+            out._baskets[name] = metas
+            out._blobs[name] = blobs
+        return out, n_cloned, n_encoded

@@ -30,7 +30,8 @@ survivor selection), ``cascade_stage``, ``fetch`` (one store read round,
 attrs: branches/bytes), ``decode``, ``decode_device`` (the
 backend-selected on-device basket decode, DESIGN.md §16), ``kernel``,
 ``device_batch`` (one per window-batched cascade dispatch group, attrs:
-windows/pad_windows/pad_events), ``write``, ``shard``, ``merge``,
+windows/pad_windows/pad_events), ``write`` (a job's output store built,
+attrs: baskets_cloned/baskets_encoded, DESIGN.md §18), ``shard``, ``merge``,
 ``job``, ``admission``, ``queue``, ``settle``, and the fault-tolerance
 kinds ``retry`` (one per re-issued shard, attrs: failed/used node),
 ``hedge`` (one per hedged shard, attrs: outcome won/lost/cancelled),

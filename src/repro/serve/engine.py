@@ -30,7 +30,6 @@ from repro.core.engine import (
     NetworkModel,
     SkimResult,
     WindowPartial,
-    _concat_output,
     _decode_branches,
     _select_columns,
     _skipped_requests,
@@ -263,7 +262,7 @@ class SharedScanEngine:
         # per-query accumulation state
         per_b = [Breakdown() for _ in plans]
         per_stats = [FetchStats() for _ in plans]
-        out_cols = [{k: [] for k in p.output_branches} for p in plans]
+        out_pieces: list[list[tuple]] = [[] for _ in plans]
         jagged_maps: list[dict[str, str]] = [{} for _ in plans]
         n_passed = [0] * len(plans)
         pad_K = [0] * len(plans)  # monotonic per-query pad shapes
@@ -471,8 +470,7 @@ class SharedScanEngine:
                     )
                 tr.end(p2sid, bytes=per_stats[i].bytes_fetched)
                 jagged_maps[i].update(jagged)
-                for k2, v in cols.items():
-                    out_cols[i][k2].append(v)
+                out_pieces[i].append((start, stop, k, cols))
                 tenant_parts[i].cols = cols
                 tenant_parts[i].jagged = jagged
             if data is not None and executors and all(
@@ -504,8 +502,10 @@ class SharedScanEngine:
         results: list[SkimResult] = []
         for i, plan in enumerate(plans):
             b = per_b[i]
-            cat = _concat_output(out_cols[i], n_passed[i], plan, store)
-            out = _write_output(cat, jagged_maps[i], store, b)
+            out = _write_output(
+                store, out_pieces[i], plan.output_branches, jagged_maps[i], b,
+                tr, tenant=i,
+            )
             b.fetch = self.input_link.transfer_time(
                 per_stats[i].bytes_fetched, per_stats[i].requests
             )
