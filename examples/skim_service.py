@@ -14,7 +14,6 @@ import argparse
 
 from repro.core.engine import NetworkModel, SkimEngine
 from repro.data.synth import make_nanoaod_like
-from repro.serve.engine import SharedScanEngine
 
 QUERY = {
     "branches": ["Electron_*", "Muon_*", "Jet_*", "MET_*", "HLT_*"]
@@ -90,7 +89,7 @@ def main() -> None:
                             "min_count": 2}]}),
         tenant({}),
     ]
-    batch = SharedScanEngine(store).run_batch(tenants)
+    batch = SkimEngine(store).run_batch(tenants)
     print(f"\nshared scan: {batch.n_queries} tenant queries, one pass")
     for i, r in enumerate(batch.results):
         print(f"  tenant {i}: {r.n_passed}/{r.n_input} events "

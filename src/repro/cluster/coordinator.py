@@ -58,11 +58,11 @@ from repro.cluster.retry import (
     RetryPolicy,
     classify_fault,
 )
-from repro.core.engine import Breakdown, SkimResult, _skipped_requests, drain
+from repro.core.engine import Breakdown, SkimResult, drain
 from repro.core.planner import plan_skim
 from repro.core.query import Query, parse_query
 from repro.core.zonemap import PRUNE, classify_span
-from repro.data.store import CorruptBasket, EventStore, FetchStats
+from repro.data.store import CorruptBasket, EventStore, FetchStats, coalesced_requests
 from repro.obs.schema import SkimReport, make_extras
 from repro.obs.trace import NULL_TRACER, Tracer
 
@@ -447,7 +447,7 @@ class ClusterCoordinator:
         stats = FetchStats()
         for a, bnd in spans:
             nbytes, nb = st.range_comp_bytes(plan.filter_branches, a, bnd)
-            stats.skip(nbytes, _skipped_requests(nbytes, nb, True))
+            stats.skip(nbytes, coalesced_requests(nbytes, nb, True))
         cols = {
             name: np.empty(0, dtype=st.branches[name].np_dtype())
             for name in plan.output_branches

@@ -3,8 +3,8 @@
 A :class:`StorageNode` is the cluster's unit of placement and failure —
 the near-storage server (DPU analogue) that owns one shard and runs the
 PR-1 fast path against it: a per-shard
-:class:`~repro.core.engine.SkimEngine` for single queries and a
-:class:`~repro.serve.engine.SharedScanEngine` for multi-tenant batches.
+:class:`~repro.core.engine.SkimEngine` for single queries and
+multi-tenant batches alike.
 Its link tiers are its own (``near_input_link`` for the storage-side
 fetch the prefetcher hides, ``output_link`` for survivors crossing back
 to the client), so a cluster can model heterogeneous fleets.
@@ -28,10 +28,16 @@ import time
 from dataclasses import dataclass, field
 
 from repro.cluster.shard import Shard
-from repro.core.engine import PCIE_128G, NetworkModel, SkimEngine, SkimResult, WAN_1G
+from repro.core.engine import (
+    PCIE_128G,
+    WAN_1G,
+    NetworkModel,
+    SharedScanResult,
+    SkimEngine,
+    SkimResult,
+)
 from repro.core.query import Query, parse_query
 from repro.data.store import CorruptBasket
-from repro.serve.engine import SharedScanEngine, SharedScanResult
 
 FAULT_KINDS = ("fail", "straggle", "corrupt")
 
@@ -91,7 +97,7 @@ def modeled_node_seconds(result: SkimResult) -> float:
 
 
 class StorageNode:
-    """One shard + the engines that serve it."""
+    """One shard + the engine that serves it."""
 
     def __init__(
         self,
@@ -120,17 +126,6 @@ class StorageNode:
             fused=fused,
             pipeline=pipeline,
             near_input_link=near_input_link,
-            prune=prune,
-            cascade=cascade,
-            device_batch=device_batch,
-            fused_backend=fused_backend,
-        )
-        self.shared_engine = SharedScanEngine(
-            shard.store,
-            input_link=near_input_link,
-            output_link=output_link,
-            chunk_events=shard.window_events,
-            fused=fused,
             prune=prune,
             cascade=cascade,
             device_batch=device_batch,
@@ -247,7 +242,7 @@ class StorageNode:
         )
         t0 = time.perf_counter()
         try:
-            batch = self.shared_engine.run_batch(queries, tracer=tracer)
+            batch = self.engine.run_batch(queries, tracer=tracer)
         except CorruptBasket as exc:
             self.quarantine.add(
                 (self.shard.shard_id, exc.branch, exc.basket_id)

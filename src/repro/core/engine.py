@@ -41,11 +41,12 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.core.branchmap import with_counts_branches
+from repro.core.plan import CascadeExecutor, mark_fetched, unfetched_bytes
 from repro.core.planner import SkimPlan, plan_skim
 from repro.core.query import Query, eval_stage, parse_query
 from repro.core.zonemap import ACCEPT_ALL, PRUNE, SCAN
 from repro.data.store import (
-    TTREECACHE_BYTES,  # noqa: F401  (re-export; serve + tests import via engine)
     EventStore,
     FetchStats,
     WindowPrefetcher,
@@ -168,20 +169,117 @@ class WindowPartial:
     decision: str = SCAN  # zone-map kind this window resolved as
 
 
+@dataclass
+class BatchWindowPartial:
+    """One basket window of a shared scan, streamed per tenant.
+
+    ``tenants[i]`` is tenant *i*'s :class:`WindowPartial` for this window
+    — survivor columns exactly as they will land in that tenant's final
+    output, so per-tenant unions of streamed partials are bit-identical
+    to the batch result by construction (DESIGN.md §12).
+    """
+
+    index: int
+    start: int
+    stop: int
+    tenants: list  # per tenant, request order: WindowPartial
+
+
+@dataclass
+class SharedScanResult:
+    """Batch result of one shared scan over N tenant queries."""
+
+    results: list[SkimResult]  # per-query, in request order
+    shared_stats: FetchStats  # the single phase-1 pass (union branches)
+    shared_breakdown: Breakdown  # fetch/decode of that pass (+ modeled link)
+    naive_phase1_bytes: int  # what N independent scans would have fetched
+    wall_s: float = 0.0
+
+    @property
+    def n_queries(self) -> int:
+        return len(self.results)
+
+    @property
+    def saved_bytes(self) -> int:
+        """Phase-1 bytes the shared scan avoided vs N independent skims."""
+        return self.naive_phase1_bytes - self.shared_stats.bytes_fetched
+
+    @property
+    def amortization(self) -> float:
+        """naive/shared phase-1 byte ratio (>= 1; ~N for similar queries)."""
+        return self.naive_phase1_bytes / max(self.shared_stats.bytes_fetched, 1)
+
+
 def drain(gen):
     """Drive a partial-yielding executor generator to its final result.
 
     The streaming executors are generators that yield
-    :class:`WindowPartial` (or the shared-scan batch equivalent) per
-    window and *return* the final result object — ``drain`` is the
-    synchronous caller's one-liner to discard the stream and keep the
-    result.
+    :class:`WindowPartial` (or :class:`BatchWindowPartial`) per window and
+    *return* the final result object — ``drain`` is the synchronous
+    caller's one-liner to discard the stream and keep the result.
     """
     while True:
         try:
             next(gen)
         except StopIteration as stop:
             return stop.value
+
+
+def _solo(batch):
+    """A batch of one's stream as a solo run's: its tenant's partial per
+    window, then its one :class:`SkimResult`.  Closing this stream closes
+    the batch (cancellation reaches the window loop)."""
+    try:
+        while True:
+            try:
+                part = next(batch)
+            except StopIteration as stop:
+                return stop.value.results[0]
+            yield part.tenants[0]
+    finally:
+        batch.close()
+
+
+@dataclass
+class _Tenant:
+    """One query's state across the window loop."""
+
+    plan: SkimPlan
+    program: object = None  # compiled predicate program (fused runs)
+    ex: CascadeExecutor | None = None  # its cascade, when cascaded
+    b: Breakdown = field(default_factory=Breakdown)
+    p2: FetchStats = field(default_factory=FetchStats)  # phase-2 fetches
+    # (start, stop, n_passed, survivor columns) of every window with
+    # survivors: what the output store is built from at the end
+    pieces: list = field(default_factory=list)
+    jagged: dict = field(default_factory=dict)
+    # per-window survivor ledger: (start, stop, n_passed) for EVERY
+    # window, survivors or not — the mergeable-result contract the
+    # cluster coordinator splits shard outputs with (DESIGN.md §5)
+    rows: list = field(default_factory=list)
+    n_passed: int = 0
+    pad_K: int = 0  # grows monotonically so padded shapes (and compiled
+    # kernels) stay stable once the max multiplicity has been seen
+
+    @property
+    def decisions(self):
+        """Zone-map decisions (DESIGN.md §9), one per window, or None when
+        pruning is off / nothing was provable."""
+        return self.plan.window_decisions
+
+    def kind(self, wi: int) -> str:
+        decisions = self.decisions
+        return decisions[wi].decision if decisions is not None else SCAN
+
+
+def _union(lists) -> list[str]:
+    """First-seen-order union of branch lists."""
+    return list(dict.fromkeys(name for names in lists for name in names))
+
+
+def _tenant_attr(solo: bool, i: int) -> dict:
+    """Span attrs naming the tenant — a shared scan's only."""
+    return {} if solo else {"tenant": i}
 
 
 class _Timer:
@@ -267,13 +365,6 @@ def _decode_branches(
     return data
 
 
-def _skipped_requests(nbytes: int, n_baskets: int, coalesce: bool) -> int:
-    """Requests a skipped fetch round would have issued — the store's
-    TTreeCache request model (:func:`repro.data.store.coalesced_requests`),
-    re-exported under the pricing-side name."""
-    return coalesced_requests(nbytes, n_baskets, coalesce)
-
-
 def _pipeline_schedule(
     records: list[dict], link: NetworkModel, depth: int = 2
 ) -> float:
@@ -318,9 +409,8 @@ def _window_phase2(
     coalesce: bool,
     tracer=None,
 ) -> tuple[dict, dict]:
-    """Phase 2 for one surviving window: fetch the output-only branches and
-    select survivor columns (shared by the single-query executor and the
-    shared-scan service — the two must stay bit-identical).
+    """Phase 2 for one surviving window outside the basket ledger: fetch
+    the output-only branches and select survivor columns.
 
     The fetch set is every output branch not already decoded: for scanned
     windows ``loaded`` holds the filter branches, so this is exactly the
@@ -412,8 +502,9 @@ def _write_output(
 
 
 class SkimEngine:
-    """Runs a :class:`Query` against an :class:`EventStore` in one of the
-    paper's four execution modes.
+    """Runs :class:`Query` objects against an :class:`EventStore`: one
+    query in one of the paper's four execution modes, or a batch of
+    tenant queries over one shared pass (DESIGN.md §4c).
 
     ``fused`` / ``pipeline`` control the ``near_data`` executor only (the
     DPU analogue is where the fast path lives): ``fused=True`` evaluates
@@ -503,12 +594,12 @@ class SkimEngine:
         cascade: bool | None = None,
         tracer=None,
     ) -> SkimResult:
-        plan, args = self._prepare(
-            query, mode, fused, pipeline, prune, cascade, tracer
+        plans, args = self._prepare(
+            [query], mode, fused, pipeline, prune, cascade, tracer
         )
         if args is None:  # client_plain: the one-pass legacy path
-            return self._run_client_plain(plan)
-        return drain(self._iter_two_phase(plan, **args))
+            return self._run_client_plain(plans[0])
+        return drain(self._iter_two_phase(plans, **args)).results[0]
 
     def iter_run(
         self,
@@ -529,71 +620,88 @@ class SkimEngine:
         schedules on (DESIGN.md §12): window boundaries are the
         cancellation points, and the stream of partials is the partial-
         result feed.  Identical accounting and output to :meth:`run` by
-        construction — ``run`` is ``drain(iter_run(...))``.
+        construction — both run the window loop over a batch of one.
         ``client_plain`` has no window loop and cannot stream.
         """
-        plan, args = self._prepare(
-            query, mode, fused, pipeline, prune, cascade, tracer
+        plans, args = self._prepare(
+            [query], mode, fused, pipeline, prune, cascade, tracer
         )
         if args is None:
             raise ValueError("client_plain is a one-pass mode; nothing to stream")
-        return self._iter_two_phase(plan, **args)
+        return _solo(self._iter_two_phase(plans, **args))
+
+    def run_batch(
+        self, queries: list[Query | dict | str], tracer=None
+    ) -> SharedScanResult:
+        return drain(self.iter_batch(queries, tracer=tracer))
+
+    def iter_batch(self, queries: list[Query | dict | str], tracer=None):
+        """N tenant queries over ONE near-data pass of the store: a
+        generator yielding one :class:`BatchWindowPartial` per basket
+        window (every tenant's ledger entry for that window together,
+        since the scan is shared) and returning the final
+        :class:`SharedScanResult`.  Per-tenant outputs are bit-identical
+        to running each query alone; a batch of one *is* the solo run.
+        Window boundaries are the job service's cancellation points; a
+        tenant cancelled mid-batch simply stops collecting its partials —
+        the shared pass is one fetch either way (DESIGN.md §12)."""
+        plans, args = self._prepare(queries, "near_data", tracer=tracer)
+        return self._iter_two_phase(plans, **args)
 
     def _prepare(
         self,
-        query: Query | dict | str,
+        queries: list,
         mode: str,
-        fused: bool | None,
-        pipeline: bool | str | None,
-        prune: bool | None,
-        cascade: bool | None,
+        fused: bool | None = None,
+        pipeline: bool | str | None = None,
+        prune: bool | None = None,
+        cascade: bool | None = None,
         tracer=None,
-    ) -> tuple[SkimPlan, dict | None]:
-        """Shared argument resolution + planning for run / iter_run.
+    ) -> tuple[list[SkimPlan], dict | None]:
+        """Argument resolution + planning for every entry point.
 
-        Returns ``(plan, two_phase_kwargs)``; ``None`` kwargs means
+        Returns ``(plans, two_phase_kwargs)``; ``None`` kwargs means
         client_plain (the legacy one-pass path)."""
         tr = tracer if tracer is not None else self.tracer
-        if not isinstance(query, Query):
-            query = parse_query(query)
+        queries = [q if isinstance(q, Query) else parse_query(q) for q in queries]
         do_prune = (self.prune if prune is None else bool(prune)) and (
             mode != "client_plain"  # full-scan legacy mode: nothing to push down
         )
         use_fused = self.fused if fused is None else fused
-        # cascade resolution: explicit call arg > query flag > engine
-        # default; the cascade lives on the near-data fused fast path
-        # only (the other modes are the paper's fixed comparison points)
-        if cascade is None:
-            cascade = query.cascade if query.cascade is not None else self.cascade
-        do_cascade = bool(cascade) and mode == "near_data" and use_fused
+
+        def wants_cascade(query: Query) -> bool:
+            # explicit call arg > query flag > engine default; the cascade
+            # lives on the near-data fused fast path only (the other modes
+            # are the paper's fixed comparison points)
+            flag = cascade
+            if flag is None:
+                flag = query.cascade if query.cascade is not None else self.cascade
+            return bool(flag) and mode == "near_data" and use_fused
+
         plan_t0 = tr.now()
-        plan = plan_skim(
-            query, self.store, window_events=self.chunk_events, prune=do_prune,
-            cascade=do_cascade,
-        )
-        plan_t = (plan_t0, tr.now())
+        plans = [
+            plan_skim(
+                q, self.store, window_events=self.chunk_events, prune=do_prune,
+                cascade=wants_cascade(q),
+            )
+            for q in queries
+        ]
+        args = dict(tracer=tr, plan_t=(plan_t0, tr.now()), mode=mode)
         if mode == "client_plain":
-            return plan, None
+            return plans, None
         if mode == "client_opt":
-            return plan, dict(
-                mode=mode, link=self.input_link, coalesce=True,
-                tracer=tr, plan_t=plan_t,
-            )
+            return plans, dict(args, link=self.input_link, coalesce=True)
         if mode == "server_side":
-            return plan, dict(
-                mode=mode, link=LOCAL_DISK, coalesce=False,
-                tracer=tr, plan_t=plan_t,
-            )
+            return plans, dict(args, link=LOCAL_DISK, coalesce=False)
         if mode == "near_data":
             prefetch = self.pipeline if pipeline is None else pipeline
             if prefetch not in (False, True, "threads"):
                 raise ValueError(
                     f"pipeline must be False, True, or 'threads', got {prefetch!r}"
                 )
-            return plan, dict(
-                mode=mode, link=self.near_input_link, coalesce=True,
+            return plans, dict(
+                args, link=self.near_input_link, coalesce=True,
                 fused=use_fused, prefetch=prefetch,
-                tracer=tr, plan_t=plan_t,
             )
         raise ValueError(f"unknown mode {mode}")
 
@@ -632,7 +740,7 @@ class SkimEngine:
 
     def _iter_two_phase(
         self,
-        plan: SkimPlan,
+        plans: list[SkimPlan],
         mode: str,
         link: NetworkModel,
         coalesce: bool,
@@ -641,91 +749,115 @@ class SkimEngine:
         tracer=None,
         plan_t: tuple | None = None,
     ):
-        """Generator core of the two-phase executor: yields a
-        :class:`WindowPartial` per window, returns the :class:`SkimResult`."""
-        tracer = tracer if tracer is not None else NULL_TRACER
-        store, b, stats = self.store, Breakdown(), FetchStats()
-        n = store.n_events
-        chunk = self.chunk_events
+        """The window loop over N >= 1 tenant plans: yields a
+        :class:`BatchWindowPartial` per window, returns the
+        :class:`SharedScanResult`.
 
-        # the query root span stays open across the whole generator; each
-        # child span closes before the window's partial yields, so a
-        # consumer observing the stream never sees a half-open child
-        qsid = tracer.begin(
-            "query", kind="query", mode=mode, n_events=n, fused=fused
-        )
+        Every decision that differs between a solo run (N = 1) and a
+        shared scan (N > 1) is taken here from ``solo`` — DESIGN.md §4c
+        lists them: the load set, ACCEPT_ALL's phase 2, where phase-1
+        bytes are charged, the spans, and the staged evaluator's lazy
+        fetch."""
+        tracer = tracer if tracer is not None else NULL_TRACER
+        store, chunk = self.store, self.chunk_events
+        n = store.n_events
+        t_start = time.perf_counter()
+        solo = len(plans) == 1
+
+        # the root span stays open across the whole generator; each child
+        # span closes before the window's partial yields, so a consumer
+        # observing the stream never sees a half-open child
+        if solo:
+            rsid = tracer.begin(
+                "query", kind="query", mode=mode, n_events=n, fused=fused
+            )
+        else:
+            rsid = tracer.begin(
+                "batch", kind="query", n_tenants=len(plans), n_events=n
+            )
         if plan_t is not None:
             tracer.add_span("plan", kind="plan", t0=plan_t[0], t1=plan_t[1])
 
-        # (start, stop, n_passed, survivor columns) of every window with
-        # survivors: what the output store is built from at the end
-        out_pieces: list[tuple] = []
-        jagged_map: dict[str, str] = {}
-        n_passed = 0
-        phase2_stats = FetchStats()
-
-        program = plan.compiled_program() if fused else None
+        dispatches0 = None
         if fused:
             # one-time executor warm-up (module imports + backend init)
             # outside the stage timers: measured stages report steady-state
             # compute, not interpreter start-up (DESIGN.md §2c)
             import jax
 
-            from repro.kernels import ops  # noqa: F401
-
-            jax.default_backend()
-        # cascaded phase 1 (DESIGN.md §11): one executor per run owns the
-        # adaptive stage order; the prefetcher loads only the pinned head
-        # stage, later stages fetch alive baskets on demand
-        cascade_exec = None
-        dispatches0 = None
-        if fused:
+            from repro.core.neardata import (
+                fused_window_skim,
+                program_eval_np,
+                window_pad_K,
+            )
             from repro.kernels.ops import dispatch_stats
 
+            jax.default_backend()
             dispatches0 = dispatch_stats()["dispatches"]
-        if fused and plan.cascade is not None:
-            from repro.core.plan import CascadeExecutor, mark_fetched
-
-            cascade_exec = CascadeExecutor(
-                plan, store, coalesce=coalesce, tracer=tracer,
-                backend=self.fused_backend,
+        # cascaded phase 1 (DESIGN.md §11): one executor per tenant owns
+        # its adaptive stage order; the load stage fetches only the pinned
+        # head stages, later stages fetch alive baskets on demand
+        tenants = [
+            _Tenant(
+                plan=p,
+                program=p.compiled_program() if fused else None,
+                ex=CascadeExecutor(
+                    p, store, coalesce=coalesce, tracer=tracer,
+                    backend=self.fused_backend,
+                )
+                if p.cascade is not None
+                else None,
             )
+            for p in plans
+        ]
+        all_cascade = bool(tenants) and all(t.ex is not None for t in tenants)
+        any_cascade = any(t.ex is not None for t in tenants)
+        # the filter union prices pruned windows and the cascade's savings
+        # (what a full filter preload would move); ``heads`` is what the
+        # load stage fetches for a scanned window: each tenant's pinned
+        # head stage when cascading, its full filter set otherwise
+        filter_union = _union(t.plan.filter_branches for t in tenants)
+        heads = _union(
+            t.ex.head_branches if t.ex is not None else t.plan.filter_branches
+            for t in tenants
+        )
         use_threads = prefetch == "threads"
-        preload = fused or bool(prefetch)
-        # zone-map decisions (DESIGN.md §9): one per chunk window, or None
-        # when pruning is off / nothing was provable
-        decisions = plan.window_decisions
-        # per-window load/process records feeding the explicit pipeline
-        # schedule model (DESIGN.md §4b)
-        win_records: list[dict] = []
+        # a shared scan always loads its union up front; a solo run loads
+        # in a separate stage only on the fused or pipelined paths
+        preload = fused or bool(prefetch) or not solo
+
+        def load_set(wi: int):
+            """Window ``wi``'s load set: ``None`` when every tenant prunes
+            it (DESIGN.md §9), else the branches the load stage fetches."""
+            kinds = [t.kind(wi) for t in tenants]
+            if all(k == PRUNE for k in kinds):
+                return None
+            if solo and kinds[0] == ACCEPT_ALL:
+                # every event survives: the one coalesced round that phase
+                # 2 would pay moves into the load stage
+                return tenants[0].plan.output_branches
+            # a shared scan loads its heads even where every tenant
+            # accepts: their phase 2 reuses the decoded union
+            return heads
+
+        loads = [load_set(wi) for wi in range(-(-n // chunk) if n else 0)]
 
         def load_window(start: int, stop: int):
-            """Fetch + decode one window's filter branches (in "threads"
-            mode this runs in the prefetch worker; all accounting is
+            """Fetch + decode one window's load set (in "threads" mode
+            this runs in the prefetch worker; all accounting is
             window-local and merged in window order by the consumer, so
             pipelined byte/request stats are identical to the serial
-            schedule).  Zone-map decided windows (DESIGN.md §9): *prune*
-            never touches the store at all; *accept-all* loads the full
-            output set instead — every event survives, so the one
-            coalesced round that phase 2 would pay moves into the load
-            stage and keeps the double-buffered overlap."""
-            kind = (
-                decisions[start // chunk].decision
-                if decisions is not None
-                else SCAN
-            )
-            if kind == PRUNE:
-                return None, Breakdown(), FetchStats()
-            if kind != SCAN:
-                names = plan.output_branches
-            elif cascade_exec is not None:
-                # cascaded phase 1: prefetch ONLY the pinned head stage;
-                # the remaining stages fetch alive baskets on demand in
-                # the process step (DESIGN.md §11)
-                names = cascade_exec.head_branches
-            else:
-                names = plan.filter_branches
+            schedule)."""
+            names = loads[start // chunk]
             lb, ls = Breakdown(), FetchStats()
+            if names is None:
+                # provably no survivor for anyone: neither phase happens;
+                # account what the skipped filter round would have moved
+                nbytes, nb = store.range_comp_bytes(filter_union, start, stop)
+                ls.skip(nbytes, coalesced_requests(nbytes, nb, coalesce))
+                return None, lb, ls
+            if not preload:
+                return None, lb, ls
             # the prefetch worker thread must not touch the consumer's
             # span stack; its loads go untraced in "threads" mode (the
             # serial schedules trace them as load_window spans)
@@ -739,215 +871,189 @@ class SkimEngine:
             ltr.end(lsid, bytes=ls.bytes_fetched)
             return data, lb, ls
 
-        def windows():
-            if preload:
-                # all filter branches move in one coalesced round per
-                # window (the paper's TTreeCache batching); in "threads"
-                # mode the prefetcher decodes window i+1 while window i
-                # filters
-                src = WindowPrefetcher(n, chunk, load_window, enabled=use_threads)
-                for start, stop, (data, lb, ls) in src:
-                    b.merge(lb)
-                    stats.merge(ls)
-                    win_records.append(
-                        {
-                            "load_bytes": ls.bytes_fetched,
-                            "load_requests": ls.requests,
-                            "load_compute": lb.decompress + lb.deserialize,
-                        }
-                    )
-                    yield start, stop, data
-            else:
-                for start in range(0, n, chunk):
-                    yield start, min(start + chunk, n), None
+        def window_ledger(wi: int, start: int, stop: int) -> dict[str, set]:
+            """The window-shared basket ledger (DESIGN.md §11): every
+            (branch, basket) pair moves at most once per window across
+            all tenants and both phases; the loaded heads are in it."""
+            ledger: dict[str, set] = {}
+            if any_cascade and loads[wi] is heads:
+                mark_fetched(store, heads, start, stop, ledger)
+            return ledger
 
-        # device-batched cascade grouping (DESIGN.md §16): consume SCAN
-        # windows in groups of ``device_batch``, run the cascade ONCE per
-        # group (one device dispatch per stage per group, survivor masks
-        # device-resident between stages), then replay the precomputed
-        # outcomes through the unchanged per-window ledger loop below.
-        # Zone-map decided windows pass through unbatched — they never
-        # evaluate the cascade at all.
-        batch_n = self.device_batch if cascade_exec is not None else None
-        pending: dict[int, tuple] = {}
+        # device-batched cascade grouping (DESIGN.md §16): consume windows
+        # that loaded the heads in groups of ``device_batch``, run each
+        # tenant's cascade ONCE per group (one device dispatch per stage
+        # per group, survivor masks device-resident between stages), then
+        # replay the outcomes through the per-window loop below.  Other
+        # windows pass through unbatched and close the open group.
+        G = self.device_batch if all_cascade else None
+        src = enumerate(WindowPrefetcher(n, chunk, load_window, enabled=use_threads))
+        pending: dict[tuple[int, int], tuple] = {}
+        ledgers: dict[int, dict] = {}
 
-        def window_items():
-            src = enumerate(windows())
-            if not batch_n or batch_n <= 1:
+        def flush(buf: list):
+            for wi, (start, stop, _payload) in buf:
+                ledgers[wi] = window_ledger(wi, start, stop)
+            for i, t in enumerate(tenants):
+                sel = [item for item in buf if t.kind(item[0]) == SCAN]
+                if not sel:
+                    continue
+                entries = [
+                    (start, stop, data, Breakdown(), FetchStats(), ledgers[wi])
+                    for wi, (start, stop, (data, _lb, _ls)) in sel
+                ]
+                outs = t.ex.run_window_batch(entries, pad_B=G)
+                for (wi, _w), entry, out in zip(sel, entries, outs):
+                    pending[i, wi] = (out, entry[3], entry[4])
+            items = list(buf)
+            buf.clear()
+            return items
+
+        def grouped():
+            if not G or G <= 1:
                 yield from src
                 return
             buf: list = []
-
-            def flush():
-                if not buf:
-                    return
-                entries, metas = [], []
-                for _wi, (start_, stop_, preloaded_) in buf:
-                    wb_, w1s_, ledger_ = Breakdown(), FetchStats(), {}
-                    mark_fetched(
-                        store, cascade_exec.head_branches, start_, stop_,
-                        ledger_,
-                    )
-                    entries.append(
-                        (start_, stop_, preloaded_, wb_, w1s_, ledger_)
-                    )
-                    metas.append((wb_, w1s_, ledger_))
-                outs = cascade_exec.run_window_batch(entries, pad_B=batch_n)
-                for (_wi, _win), out, meta in zip(buf, outs, metas):
-                    pending[_wi] = (out, *meta)
-                items = list(buf)
-                buf.clear()
-                yield from items
-
             for item in src:
-                kind_ = (
-                    decisions[item[0]].decision
-                    if decisions is not None
-                    else SCAN
-                )
-                if kind_ == SCAN:
+                if loads[item[0]] is heads:
                     buf.append(item)
-                    if len(buf) == batch_n:
-                        yield from flush()
+                    if len(buf) == G:
+                        yield from flush(buf)
                 else:
-                    yield from flush()
+                    yield from flush(buf)
                     yield item
-            yield from flush()
+            yield from flush(buf)
 
-        # per-window survivor ledger: (start, stop, n_passed) for EVERY
-        # window, survivors or not — the mergeable-result contract the
-        # cluster coordinator splits shard outputs with (DESIGN.md §5)
-        window_rows: list[tuple[int, int, int]] = []
-        t_phase = time.perf_counter()
-        pad_K = 0  # grows monotonically so padded shapes (and compiled
-        # kernels) stay stable across windows once the max multiplicity
-        # has been seen
-        for wi, (start, stop, preloaded) in window_items():
+        shared_b, p1 = Breakdown(), FetchStats()  # the load stage + phase 1
+        # per-window load/process records feeding the explicit pipeline
+        # schedule model (DESIGN.md §4b); solo runs only
+        records: list[dict] = []
+
+        def tenant_window(i: int, t: _Tenant, wi, start, stop, data, ledger):
+            """One tenant's window: phase 1 by decision kind, then phase
+            2.  Returns its partial and whether its baskets went through
+            the window ledger."""
             m = stop - start
-            dec = decisions[wi] if decisions is not None else None
-            kind = dec.decision if dec is not None else SCAN
-            wsid = tracer.begin(
-                f"window[{wi}]", kind="window", index=wi, decision=kind
-            )
+            plan, kind = t.plan, t.kind(wi)
+            # window-local processing breakdown/stats (merged into the run
+            # totals below; also feeds the pipeline schedule model)
+            wb, w1s, w2s = Breakdown(), FetchStats(), FetchStats()
+            loaded = data if data is not None else {}
             dev_cols: dict[str, np.ndarray] = {}
-            # window-local processing breakdown/stats (merged into the
-            # run totals below; also feeds the pipeline schedule model)
-            wb, w2s = Breakdown(), FetchStats()
-            # cascade per-window state: the basket dedup ledger and the
-            # window outcome (None on the non-cascaded paths)
-            ledger: dict[str, set] = {}
-            outcome = None
-            w1s = FetchStats()
+            known: dict = {}
+            via_ledger = False
             if kind == PRUNE:
-                # provably no survivor: phase 1 AND phase 2 never happen;
-                # account what the skipped fetch round would have moved
-                stats.skip(
-                    dec.p1_bytes,
-                    _skipped_requests(dec.p1_bytes, dec.p1_baskets, coalesce),
-                )
-                loaded = {}
+                # provably no survivor: phase 1 AND phase 2 never happen
                 mask = np.zeros(m, dtype=bool)
             elif kind == ACCEPT_ALL:
-                # provably all survive: skip predicate fetch+eval — the
-                # output set moves in ONE round (preloaded in the load
-                # stage when pipelining, fetched by phase 2 below
-                # otherwise); filter-only branches never move at all
-                stats.skip(
-                    dec.extra_bytes,
-                    0 if coalesce else dec.extra_baskets,
-                )
-                loaded = preloaded if preloaded is not None else {}
+                # provably all survive: no predicate fetch or eval
                 mask = np.ones(m, dtype=bool)
-            elif cascade_exec is not None:
+                if solo:
+                    # the output set moved in the load stage (or moves in
+                    # phase 2); filter-only branches never move at all
+                    dec = t.decisions[wi]
+                    w1s.skip(dec.extra_bytes, 0 if coalesce else dec.extra_baskets)
+                elif t.ex is not None:
+                    # phase 2 flows through the window ledger (the fused
+                    # payload shortcut needs the full filter preload the
+                    # cascade deliberately no longer does)
+                    via_ledger, known = True, loaded
+                elif fused and plan.filter_branches:
+                    # the fused executor's decision short-circuit skips
+                    # predicate eval and passes the payload through whole
+                    with _Timer(wb, "filter"):
+                        mask, dev_cols = fused_window_skim(
+                            loaded, t.program, store,
+                            payload_branches=plan.payload_branches,
+                            decision=ACCEPT_ALL, backend=self.fused_backend,
+                        )
+            elif t.ex is not None:
                 # ---- phase 1 (cascaded path, DESIGN.md §11): stages run
                 # cheapest-and-most-selective-first; stage k fetches its
                 # branches only for baskets still alive after stage k-1 ----
-                loaded = {}
-                if wi in pending:
+                if (i, wi) in pending:
                     # batched path: the cascade already ran for this
                     # window's group — adopt its outcome and per-window
-                    # ledgers (byte/time accounting is window-local in
-                    # the batch too, so totals match the per-window path)
-                    outcome, cwb, w1s, ledger = pending.pop(wi)
+                    # ledgers (byte/time accounting is window-local in the
+                    # batch too, so totals match the per-window path)
+                    outcome, cwb, w1s = pending.pop((i, wi))
                     wb.merge(cwb)
                 else:
-                    mark_fetched(
-                        store, cascade_exec.head_branches, start, stop, ledger
-                    )
-                    outcome = cascade_exec.run_window(
-                        start, stop, preloaded, wb, w1s, ledger=ledger
+                    outcome = t.ex.run_window(
+                        start, stop, data, wb, w1s, ledger=ledger
                     )
                 mask = outcome.mask
-                stats.merge(w1s)
+                via_ledger, known = True, {**loaded, **outcome.full_loaded}
             elif fused:
                 # ---- phase 1 (fused path): one pass evaluates the
                 # compiled predicate AND compacts [index]+payload rows ----
-                from repro.core.neardata import (
-                    fused_window_skim,
-                    program_eval_np,
-                    window_pad_K,
-                )
-
-                loaded = preloaded
                 if not plan.filter_branches:
                     # no present branch feeds the predicate: the program is
                     # constant — all-true for a selection-free projection,
                     # all-false when only absent-era trigger ORs remain
-                    mask = program_eval_np(loaded or {}, program, m)
+                    mask = program_eval_np(loaded, t.program, m)
                 else:
-                    pad_K = max(pad_K, window_pad_K(loaded, program, store))
-                    ksid = tracer.begin("kernel", kind="kernel", window=wi)
+                    t.pad_K = max(t.pad_K, window_pad_K(loaded, t.program, store))
+                    ksid = (
+                        tracer.begin("kernel", kind="kernel", window=wi)
+                        if solo
+                        else None
+                    )
                     with _Timer(wb, "filter"):
                         mask, dev_cols = fused_window_skim(
-                            loaded, program, store,
+                            loaded, t.program, store,
                             payload_branches=plan.payload_branches,
-                            K=pad_K,
+                            K=t.pad_K,
                             pad_to=chunk,
                             backend=self.fused_backend,
                             tracer=tracer,
                         )
-                    tracer.end(ksid)
+                    if solo:
+                        tracer.end(ksid)
             else:
-                # ---- phase 1: staged filter over filter-criteria branches ----
+                # ---- phase 1: staged filter over filter-criteria branches;
+                # a solo run without a load stage fetches each stage's
+                # branches lazily, after the hierarchical early discard ----
                 mask = np.ones(m, dtype=bool)
-                loaded = dict(preloaded) if preloaded is not None else {}
+                loaded = dict(loaded)
                 for stage_name, stage in plan.query.stages():
                     if not stage:
                         continue
                     if not mask.any():
-                        break  # hierarchical early discard: skip later stages
+                        break  # early discard: skip later stages
                     need = [
                         x
                         for x in sorted(plan.query.stage_branches(stage_name))
                         if x not in loaded and x in store.branches
                     ]
-                    from repro.core.branchmap import with_counts_branches
-
                     need = [
                         x for x in with_counts_branches(need, store) if x not in loaded
                     ]
-                    loaded.update(
-                        _decode_branches(
-                            store, need, start, stop, wb, stats, coalesce,
-                            preloaded=loaded,
+                    if need:
+                        loaded.update(
+                            _decode_branches(
+                                store, need, start, stop, wb, w1s, coalesce,
+                                preloaded=loaded,
+                            )
                         )
-                    )
                     with _Timer(wb, "filter"):
                         mask &= eval_stage(stage, loaded, m)
 
             k = int(mask.sum())
-            window_rows.append((start, stop, k))
-            part_cols: dict = {}
-            part_jagged: dict = {}
+            t.rows.append((start, stop, k))
+            part = WindowPartial(
+                index=wi, start=start, stop=stop, n_passed=k,
+                cols={}, jagged={}, decision=kind,
+            )
             if k:
-                n_passed += k
-                p2sid = tracer.begin("phase2", kind="phase2", window=wi)
-                if outcome is not None:
-                    # ---- phase 2 (cascaded window): the basket ledger
-                    # dedups against phase 1, so filter∩output branches a
-                    # stage already moved are not paid again ----
-                    known = {**(preloaded or {}), **outcome.full_loaded}
-                    full = cascade_exec.fetch_full(
+                t.n_passed += k
+                p2sid = tracer.begin(
+                    "phase2", kind="phase2", **_tenant_attr(solo, i), window=wi
+                )
+                if via_ledger:
+                    # ---- phase 2 through the window ledger: baskets a
+                    # stage (or an earlier tenant) moved are not re-paid ----
+                    full = t.ex.fetch_full(
                         plan.output_branches, start, stop, wb, w2s, ledger,
                         known=known,
                     )
@@ -963,26 +1069,14 @@ class SkimEngine:
                         w2s, coalesce, tracer=tracer,
                     )
                 tracer.end(p2sid, bytes=w2s.bytes_fetched)
-                jagged_map.update(jagged)
-                out_pieces.append((start, stop, k, cols))
-                part_cols, part_jagged = cols, jagged
-            if outcome is not None:
-                # savings vs the preloading reference, ledgered AFTER both
-                # phases: a filter-branch basket counts as skipped only if
-                # neither a cascade stage nor phase 2 ever moved it (phase
-                # 2 re-fetches dead baskets of filter∩output branches for
-                # surviving windows, which must not be credited)
-                from repro.core.plan import unfetched_bytes
-
-                stats.cascade_bytes_skipped += unfetched_bytes(
-                    store, plan.filter_branches, start, stop, ledger
-                )
-            b.merge(wb)
-            phase2_stats.merge(w2s)
-            if win_records:
-                # indexed by window (not [-1]): batched grouping consumes
-                # load records ahead of the processing loop
-                win_records[wi].update(
+                t.jagged.update(jagged)
+                t.pieces.append((start, stop, k, cols))
+                part.cols, part.jagged = cols, jagged
+            t.b.merge(wb)
+            p1.merge(w1s)
+            t.p2.merge(w2s)
+            if records:
+                records[-1].update(
                     {
                         "proc_compute": wb.decompress + wb.deserialize + wb.filter,
                         # cascaded stage fetches are non-overlapped fetch in
@@ -991,95 +1085,156 @@ class SkimEngine:
                         "p2_requests": w2s.requests + w1s.requests,
                     }
                 )
+            return part, via_ledger
+
+        t_phase = time.perf_counter()
+        for wi, (start, stop, (data, lb, ls)) in grouped():
+            shared_b.merge(lb)
+            p1.merge(ls)
+            if solo and preload:
+                records.append(
+                    {
+                        "load_bytes": ls.bytes_fetched,
+                        "load_requests": ls.requests,
+                        "load_compute": lb.decompress + lb.deserialize,
+                    }
+                )
+            wattrs = {"decision": tenants[0].kind(wi)} if solo else {}
+            wsid = tracer.begin(f"window[{wi}]", kind="window", index=wi, **wattrs)
+            ledger = ledgers.pop(wi, None)
+            if ledger is None:
+                ledger = window_ledger(wi, start, stop)
+            parts, via = [], False
+            for i, t in enumerate(tenants):
+                part, used = tenant_window(i, t, wi, start, stop, data, ledger)
+                parts.append(part)
+                via = via or used
+            if all_cascade and via:
+                # savings vs the filter-union preload, ledgered AFTER every
+                # tenant's phase 2: a filter basket counts as skipped only
+                # if nothing in the window ever moved it (phase 2
+                # re-fetches dead baskets of filter∩output branches for
+                # surviving windows, which must not be credited)
+                p1.cascade_bytes_skipped += unfetched_bytes(
+                    store, filter_union, start, stop, ledger
+                )
             # the window's ledger entry is complete: stream it.  A caller
             # that stops consuming here (cancellation) has paid exactly
             # the windows it saw — the accounting above is window-local.
-            tracer.end(wsid, n_passed=k)
+            tracer.end(wsid, n_passed=sum(p.n_passed for p in parts))
             try:
-                yield WindowPartial(
-                    index=wi, start=start, stop=stop, n_passed=k,
-                    cols=part_cols, jagged=part_jagged, decision=kind,
+                yield BatchWindowPartial(
+                    index=wi, start=start, stop=stop, tenants=parts
                 )
             except GeneratorExit:
                 # cancelled mid-stream: close the root so the partial
                 # trace still exports as a well-formed tree
-                tracer.end(qsid, cancelled=True, n_passed=n_passed)
+                tracer.end(
+                    rsid, cancelled=True,
+                    n_passed=sum(t.n_passed for t in tenants),
+                )
                 raise
         phase_wall = time.perf_counter() - t_phase
 
-        phase1_bytes = stats.bytes_fetched  # pre-merge: phase-1 only
-        stats.merge(phase2_stats)
-
-        out = _write_output(
-            store, out_pieces, plan.output_branches, jagged_map, b, tracer
-        )
-
-        b.fetch = link.transfer_time(stats.bytes_fetched, stats.requests)
-        out_bytes = out.compressed_bytes()
-        if mode in ("server_side", "near_data"):
-            # the filtered file crosses the WAN back to the client
-            b.output_transfer = self.output_link.transfer_time(out_bytes, 1)
-        compute = b.decompress + b.deserialize + b.filter + b.write
-        # double-buffered basket prefetch (the paper's "advanced data
-        # prefetching" future work, implemented for near_data): with fetch
-        # of window i+1 overlapping compute of window i, the pipeline
-        # bound is max(fetch, compute) instead of their sum.
-        overlap_total = (
-            max(b.fetch, b.decompress + b.deserialize + b.filter)
-            + b.write
-            + b.output_transfer
-        )
-        report = SkimReport(
-            mode=mode,
-            fused=fused,
-            pipelined=bool(prefetch),
-            prune=decisions is not None,
-            # cascaded phase-1 ledger (DESIGN.md §11)
-            cascade=cascade_exec is not None,
-            output_bytes=out_bytes,
-            window_rows=window_rows,
-            # zone-map pruning ledger (DESIGN.md §9): every window the
-            # analysis decided without fetching, plus the priced savings
-            # mirrored in stats.bytes_skipped / requests_skipped
-            pruned_windows=[
-                (d.start, d.stop, d.decision)
-                for d in decisions or ()
-                if d.decision != SCAN
-            ],
-            overlap_total_s=overlap_total,
-            phase_wall_s=phase_wall,
-            # phase split of stats.bytes_fetched (accept-all windows fold
-            # their single output round into phase 1 when preloading)
-            phase1_bytes=phase1_bytes,
-            phase2_bytes=phase2_stats.bytes_fetched,
-        )
-        if cascade_exec is not None:
-            report.cascade_order = cascade_exec.order()
-            report.cascade_stages = cascade_exec.state.report()
-            report.cascade_bytes_skipped = stats.cascade_bytes_skipped
-        if dispatches0 is not None:
-            from repro.kernels.ops import dispatch_stats
-
-            report.device_dispatches = (
-                dispatch_stats()["dispatches"] - dispatches0
+        results = []
+        for i, t in enumerate(tenants):
+            if solo:
+                # a batch of one owns its pass: the load stage and phase
+                # 1 are its own, as in any solo run
+                t.b = Breakdown.merged([shared_b, t.b])
+                stats = FetchStats.merged([p1, t.p2])
+            else:
+                stats = t.p2
+            b = t.b
+            out = _write_output(
+                store, t.pieces, t.plan.output_branches, t.jagged, b, tracer,
+                **_tenant_attr(solo, i),
             )
-            report.decode_backend = store.resolved_decode_backend()
-            if batch_n:
-                report.device_batch = batch_n
-        if win_records:
-            # exact double-buffered schedule from the per-window records
-            # (what the threaded prefetcher realizes on capable hosts)
-            report.pipeline_total_s = (
-                _pipeline_schedule(win_records, link)
-                + b.write
-                + b.output_transfer
+            b.fetch = link.transfer_time(stats.bytes_fetched, stats.requests)
+            out_bytes = out.compressed_bytes()
+            if mode in ("server_side", "near_data"):
+                # the filtered file crosses the WAN back to the client
+                b.output_transfer = self.output_link.transfer_time(out_bytes, 1)
+            report = SkimReport(
+                mode=mode if solo else "shared_scan",
+                fused=fused,
+                pipelined=bool(prefetch) if solo else use_threads,
+                prune=t.decisions is not None,
+                # cascaded phase-1 ledger (DESIGN.md §11)
+                cascade=t.ex is not None,
+                output_bytes=out_bytes,
+                window_rows=t.rows,
+                # zone-map pruning ledger (DESIGN.md §9): every window the
+                # analysis decided without fetching, plus the priced savings
+                # mirrored in stats.bytes_skipped / requests_skipped
+                pruned_windows=[
+                    (d.start, d.stop, d.decision)
+                    for d in t.decisions or ()
+                    if d.decision != SCAN
+                ],
+                shared_scan=not solo,
             )
-        tracer.end(qsid, n_passed=n_passed, bytes=stats.bytes_fetched)
-        return SkimResult(
-            mode, out, n, n_passed, b, stats, plan,
-            busy_fraction=compute / max(b.total(), 1e-12),
-            extras=report.legacy_extras(),
-            report=report,
+            if t.ex is not None:
+                report.cascade_order = t.ex.order()
+                report.cascade_stages = t.ex.state.report()
+            if solo:
+                # the ledger only a solo run reports.  Double-buffered
+                # basket prefetch (the paper's "advanced data prefetching"
+                # future work): with fetch of window i+1 overlapping
+                # compute of window i, the pipeline bound is
+                # max(fetch, compute) instead of their sum.
+                report.overlap_total_s = (
+                    max(b.fetch, b.decompress + b.deserialize + b.filter)
+                    + b.write
+                    + b.output_transfer
+                )
+                report.phase_wall_s = phase_wall
+                # phase split of stats.bytes_fetched (accept-all windows
+                # fold their single output round into phase 1 when
+                # preloading)
+                report.phase1_bytes = p1.bytes_fetched
+                report.phase2_bytes = t.p2.bytes_fetched
+                if t.ex is not None:
+                    report.cascade_bytes_skipped = stats.cascade_bytes_skipped
+                if dispatches0 is not None:
+                    report.device_dispatches = (
+                        dispatch_stats()["dispatches"] - dispatches0
+                    )
+                    report.decode_backend = store.resolved_decode_backend()
+                    if G:
+                        report.device_batch = G
+                if records:
+                    # exact double-buffered schedule from the per-window
+                    # records (what the threaded prefetcher realizes)
+                    report.pipeline_total_s = (
+                        _pipeline_schedule(records, link)
+                        + b.write
+                        + b.output_transfer
+                    )
+            compute = b.decompress + b.deserialize + b.filter + b.write
+            results.append(
+                SkimResult(
+                    report.mode, out, n, t.n_passed, b, stats, t.plan,
+                    busy_fraction=compute / max(b.total(), 1e-12),
+                    extras=report.legacy_extras(),
+                    report=report,
+                )
+            )
+        # phase-1 link time is paid once for the whole batch
+        shared_b.fetch = link.transfer_time(p1.bytes_fetched, p1.requests)
+        tracer.end(
+            rsid, n_passed=sum(t.n_passed for t in tenants),
+            bytes=p1.bytes_fetched + sum(t.p2.bytes_fetched for t in tenants),
+        )
+        return SharedScanResult(
+            results=results,
+            shared_stats=p1,
+            # a batch of one has no pass beside its own result's
+            shared_breakdown=Breakdown() if solo else shared_b,
+            naive_phase1_bytes=sum(
+                store.compressed_bytes(p.filter_branches) for p in plans
+            ),
+            wall_s=time.perf_counter() - t_start,
         )
 
 

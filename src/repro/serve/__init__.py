@@ -1,13 +1,11 @@
-"""Service layer: shared-scan batching + the async skim job service.
+"""Service layer: the async skim job service.
 
-:class:`SharedScanEngine` amortizes one phase-1 pass over a tenant
-batch (DESIGN.md §6); :class:`SkimService` (DESIGN.md §12) puts a job
-lifecycle in front of every backend — cost-based admission, per-tenant
-quotas, a weighted-fair queue, and window-granular streaming of partial
-results.
+:class:`SkimService` (DESIGN.md §12) puts a job lifecycle in front of
+every backend — cost-based admission, per-tenant quotas, a weighted-fair
+queue, batch coalescing onto one shared scan (``SkimEngine.iter_batch``,
+DESIGN.md §4c), and window-granular streaming of partial results.
 """
 
-from repro.serve.engine import BatchWindowPartial, SharedScanEngine, SharedScanResult
 from repro.serve.jobs import (
     CANCELLED,
     DONE,
@@ -41,7 +39,6 @@ __all__ = [
     "REJECTED",
     "RUNNING",
     "TERMINAL",
-    "BatchWindowPartial",
     "ClusterBackend",
     "CostEstimate",
     "DeterministicExecutor",
@@ -52,8 +49,6 @@ __all__ = [
     "ManualClock",
     "PartialResult",
     "ServiceError",
-    "SharedScanEngine",
-    "SharedScanResult",
     "SkimJob",
     "SkimService",
     "TenantQuota",

@@ -20,7 +20,7 @@ Design pillars:
     schedule ahead of — and preempt, at window boundaries — an
     expensive query instead of queueing behind it.
   * **Cooperative execution.**  The engines' streaming generators
-    (:meth:`SkimEngine.iter_run`, :meth:`SharedScanEngine.iter_batch`,
+    (:meth:`SkimEngine.iter_run`, :meth:`SkimEngine.iter_batch`,
     :meth:`ClusterCoordinator.iter_run`) advance one window (or shard)
     per quantum.  Window boundaries are the cancellation points, and
     every yielded partial is appended to ``job.partials`` immediately —
@@ -32,7 +32,7 @@ Design pillars:
     decision in a replayable trace.  No sleeps anywhere; tests replay
     schedules exactly.
   * **Batch coalescing.**  With ``batching=True``, compatible queued
-    jobs start as ONE :meth:`SharedScanEngine.iter_batch` pass —
+    jobs start as ONE :meth:`SkimEngine.iter_batch` pass —
     phase 1 amortizes across tenants while each job still streams its
     own partials and finishes with its own bit-identical result.
 """
@@ -50,7 +50,6 @@ from repro.obs.metrics import (
     priced_stage_bytes,
 )
 from repro.obs.trace import Tracer, chrome_trace, trace_json
-from repro.serve.engine import SharedScanEngine
 from repro.serve.journal import JobJournal
 from repro.serve.jobs import (
     CANCELLED,
@@ -85,8 +84,8 @@ class ServiceError(RuntimeError):
 
 class EngineBackend:
     """Single-store backend: solo jobs run on
-    :meth:`SkimEngine.iter_run`, coalesced batches on
-    :meth:`SharedScanEngine.iter_batch` — both stream
+    :meth:`SkimEngine.iter_run`, coalesced batches on the same engine's
+    :meth:`SkimEngine.iter_batch` — both stream
     :class:`~repro.core.engine.WindowPartial` per basket window."""
 
     supports_batch = True
@@ -95,19 +94,11 @@ class EngineBackend:
         self,
         store,
         engine: SkimEngine | None = None,
-        shared: SharedScanEngine | None = None,
         mode: str = "near_data",
         **engine_kw,
     ):
         self.store = store
         self.engine = engine or SkimEngine(store, **engine_kw)
-        self.shared = shared or SharedScanEngine(
-            store,
-            chunk_events=self.engine.chunk_events,
-            fused=self.engine.fused,
-            prune=self.engine.prune,
-            cascade=self.engine.cascade,
-        )
         self.mode = mode
 
     def price(self, query, calibration: dict | None = None) -> CostEstimate:
@@ -123,7 +114,7 @@ class EngineBackend:
         return self.engine.iter_run(query, mode=self.mode, tracer=tracer)
 
     def start_batch(self, queries, tracer=None):
-        return self.shared.iter_batch(queries, tracer=tracer)
+        return self.engine.iter_batch(queries, tracer=tracer)
 
 
 class ClusterBackend:

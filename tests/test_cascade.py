@@ -24,13 +24,12 @@ import pytest
 
 from repro.cluster import SkimResultCache, build_cluster
 from repro.cluster.cache import CACHE_KEY_VERSION, canonical_query, cache_key
-from repro.core.engine import Breakdown, run_skim
+from repro.core.engine import Breakdown, SkimEngine, run_skim
 from repro.core.plan import CascadeExecutor, CascadeState, build_cascade
 from repro.core.planner import plan_skim
 from repro.core.query import eval_stage, parse_query
 from repro.data.store import EventStore, FetchStats
 from repro.data.synth import make_nanoaod_like
-from repro.serve.engine import SharedScanEngine
 
 N_EVENTS = 12_000
 BASKET = 2048
@@ -189,8 +188,6 @@ def test_cascade_ledger_exact_multi_basket_windows(chunk):
         },
     }
     kw = dict(mode="near_data", fused=True, pipeline=False, prune=False)
-    from repro.core.engine import SkimEngine
-
     eng = SkimEngine(st, chunk_events=chunk)
     ref = eng.run(q, prune=False, cascade=False)
     res = eng.run(q, prune=False, cascade=True)
@@ -355,7 +352,7 @@ def test_shared_branch_decodes_once_per_basket():
 
 
 def test_shared_scan_cascade_matches_solo(store):
-    batch = SharedScanEngine(store, cascade=True).run_batch([QUERY, SECOND])
+    batch = SkimEngine(store, cascade=True).run_batch([QUERY, SECOND])
     for q, res in zip([QUERY, SECOND], batch.results):
         solo = run_skim(
             store, q, mode="near_data", fused=True, pipeline=False,
@@ -364,7 +361,7 @@ def test_shared_scan_cascade_matches_solo(store):
         _assert_same_output(res, solo)
         assert res.extras["cascade"]
     # the shared cascaded pass never pays the union preload in full
-    ref = SharedScanEngine(store, cascade=False).run_batch([QUERY, SECOND])
+    ref = SkimEngine(store, cascade=False).run_batch([QUERY, SECOND])
     assert (
         batch.shared_stats.bytes_fetched <= ref.shared_stats.bytes_fetched
     )

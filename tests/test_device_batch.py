@@ -25,7 +25,7 @@ import numpy as np
 import pytest
 
 from repro.cluster import build_cluster
-from repro.core.engine import Breakdown, run_skim
+from repro.core.engine import Breakdown, SkimEngine, run_skim
 from repro.core.plan import CascadeExecutor
 from repro.core.planner import plan_skim
 from repro.core.query import parse_query
@@ -33,7 +33,6 @@ from repro.data import codecs
 from repro.data.store import EventStore, FetchStats
 from repro.data.synth import make_nanoaod_like
 from repro.kernels import ops
-from repro.serve.engine import SharedScanEngine
 
 N_EVENTS = 12_000
 BASKET = 2048
@@ -140,10 +139,10 @@ def test_batched_engine_ledger_exact(store, device_batch):
 
 @pytest.mark.parametrize("device_batch", [1, 3, ALL])
 def test_batched_shared_scan_bit_identical(store, device_batch):
-    batch = SharedScanEngine(
+    batch = SkimEngine(
         store, cascade=True, device_batch=device_batch
     ).run_batch([QUERY, SECOND])
-    ref = SharedScanEngine(store, cascade=True).run_batch([QUERY, SECOND])
+    ref = SkimEngine(store, cascade=True).run_batch([QUERY, SECOND])
     for res, solo in zip(batch.results, ref.results):
         _assert_same_output(res, solo)
     assert batch.shared_stats.bytes_fetched == ref.shared_stats.bytes_fetched
@@ -161,7 +160,7 @@ def test_device_batch_validated(store):
     with pytest.raises(ValueError):
         run_skim(store, QUERY, device_batch=0)
     with pytest.raises(ValueError):
-        SharedScanEngine(store, device_batch=-2)
+        SkimEngine(store, device_batch=-2)
     with pytest.raises(ValueError):
         run_skim(store, QUERY, fused_backend="cuda")
 
@@ -381,7 +380,6 @@ def test_decode_round_counts_like_per_branch_rounds(monkeypatch, backend, capaci
     """The decode cache and tier ledgers read the same after a run with
     one round per fetch as after the same run with one round per branch,
     and so do the outputs."""
-    from repro.core.engine import SkimEngine
 
     def run():
         st = make_nanoaod_like(

@@ -32,7 +32,6 @@ from repro.data.synth import make_nanoaod_like
 from repro.kernels import ops
 from repro.obs import NULL_TRACER, Tracer, chrome_trace, trace_json
 from repro.serve import ManualClock, SkimService
-from repro.serve.engine import SharedScanEngine
 from repro.serve.service import EngineBackend
 
 N_EVENTS = 6_000
@@ -218,7 +217,7 @@ def test_fetch_is_a_store_read_only(shared):
     st = make_nanoaod_like(n_events=N_EVENTS, basket_events=BASKET, seed=7)
     tr = Tracer(clock=ManualClock())
     if shared:
-        SharedScanEngine(st, chunk_events=BASKET).run_batch([QUERY, QUERY], tracer=tr)
+        SkimEngine(st, chunk_events=BASKET).run_batch([QUERY, QUERY], tracer=tr)
     else:
         SkimEngine(st, chunk_events=BASKET).run(QUERY, tracer=tr)
     kids = _children(tr)

@@ -24,7 +24,6 @@ from repro.core.query import eval_node, eval_stage, parse_query
 from repro.core.zonemap import ACCEPT_ALL, PRUNE, SCAN, classify_windows
 from repro.data.store import EventStore
 from repro.data.synth import make_nanoaod_like
-from repro.serve.engine import SharedScanEngine
 
 try:
     from hypothesis import given, settings
@@ -283,7 +282,7 @@ def test_derived_query_shared_scan_matches_solo(store):
                {"branches": ["MET_*"], "selection": {"event": [
                    {"type": "expr", "expr": "MET_pt*2", "op": ">",
                     "value": 80.0}]}}]
-    batch = SharedScanEngine(store).run_batch(tenants)
+    batch = SkimEngine(store).run_batch(tenants)
     eng = SkimEngine(store)
     for q, res in zip(tenants, batch.results):
         _assert_same_output(res, eng.run(q, "near_data"))

@@ -41,7 +41,6 @@ from repro.obs import (
     unified_cache_report,
 )
 from repro.serve import ManualClock, SkimService
-from repro.serve.engine import SharedScanEngine
 from repro.serve.service import ClusterBackend, EngineBackend
 from tests.test_query import QUERY
 
@@ -333,7 +332,7 @@ def test_skimreport_attached_and_extras_match(store):
 
 
 def test_skimreport_shared_scan_shim(store):
-    eng = SharedScanEngine(store, chunk_events=BASKET)
+    eng = SkimEngine(store, chunk_events=BASKET)
     batch = eng.run_batch([QUERY, QUERY])
     for r in batch.results:
         assert isinstance(r.report, SkimReport)

@@ -12,7 +12,6 @@ from repro.core.engine import SkimEngine
 from repro.data.codecs import bitpack_raw_parts
 from repro.data.store import EventStore
 from repro.obs.trace import Tracer
-from repro.serve.engine import SharedScanEngine
 
 B = 64  # basket_events of every store here
 
@@ -137,7 +136,7 @@ def _run_one(store, chunk, query, executor, tracer):
         engine = SkimEngine(store, chunk_events=chunk, tracer=tracer)
         partials, result = _drive(engine.iter_run(query))
         return [(result, partials, None)]
-    engine = SharedScanEngine(store, chunk_events=chunk)
+    engine = SkimEngine(store, chunk_events=chunk)
     batches, batch = _drive(engine.iter_batch([query, SELECTIVE_QUERY], tracer=tracer))
     return [
         (res, [b.tenants[i] for b in batches], i)
@@ -194,8 +193,8 @@ def test_corrupted_blob_is_encoded_not_cloned(executor):
     if executor == "skim_engine":
         gen = SkimEngine(store, chunk_events=B, tracer=tracer).iter_run(KEEP_QUERY)
     else:
-        gen = SharedScanEngine(store, chunk_events=B).iter_batch(
-            [KEEP_QUERY], tracer=tracer
+        gen = SkimEngine(store, chunk_events=B).iter_batch(
+            [KEEP_QUERY, SELECTIVE_QUERY], tracer=tracer
         )
     first = next(gen)  # window 0 is read, kept and streamed
     good = store._blobs["MET_pt"][0]

@@ -20,7 +20,6 @@ from repro.core.planner import plan_skim
 from repro.core.query import eval_stage, parse_query
 from repro.data.store import WindowPrefetcher
 from repro.data.synth import make_nanoaod_like
-from repro.serve.engine import SharedScanEngine
 from tests.test_query import QUERY
 
 
@@ -263,7 +262,7 @@ def tenants():
 
 
 def test_shared_scan_matches_individual_runs(store, tenants):
-    batch = SharedScanEngine(store).run_batch(tenants)
+    batch = SkimEngine(store).run_batch(tenants)
     eng = SkimEngine(store)
     assert batch.n_queries == len(tenants)
     for q, res in zip(tenants, batch.results):
@@ -272,7 +271,7 @@ def test_shared_scan_matches_individual_runs(store, tenants):
 
 
 def test_shared_scan_amortizes_phase1_bytes(store, tenants):
-    batch = SharedScanEngine(store).run_batch(tenants)
+    batch = SkimEngine(store).run_batch(tenants)
     # one scan of the union must beat N scans of the parts
     assert batch.shared_stats.bytes_fetched < batch.naive_phase1_bytes
     assert batch.amortization > 1.5
@@ -291,7 +290,7 @@ def test_selection_free_query_all_paths(store):
                dict(fused=True, pipeline="threads")):
         res = run_skim(store, q, mode="near_data", **kw)
         _assert_same_output(res, ref)
-    batch = SharedScanEngine(store).run_batch([q])
+    batch = SkimEngine(store).run_batch([q])
     _assert_same_output(batch.results[0], ref)
 
 
@@ -302,7 +301,7 @@ def test_invalid_pipeline_value_rejected(store):
 
 def test_shared_scan_single_query_degenerates(store):
     """A batch of one tenant behaves like the plain engine."""
-    batch = SharedScanEngine(store).run_batch([QUERY])
+    batch = SkimEngine(store).run_batch([QUERY])
     solo = SkimEngine(store).run(QUERY, "near_data")
     _assert_same_output(batch.results[0], solo)
     assert batch.amortization == pytest.approx(1.0)

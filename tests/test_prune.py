@@ -21,10 +21,9 @@ import numpy as np
 import pytest
 
 from repro.cluster import SkimResultCache, build_cluster
-from repro.core.engine import run_skim
+from repro.core.engine import SkimEngine, run_skim
 from repro.data.store import EventStore
 from repro.data.synth import make_nanoaod_like
-from repro.serve.engine import SharedScanEngine
 
 N_EVENTS = 12_000
 BASKET = 2048
@@ -179,8 +178,8 @@ def test_prune_off_is_reference(store, reference):
 
 def test_shared_scan_pruned_matches_solo_reference(store):
     tenants = [SELECTIVE, ACCEPT]
-    batch = SharedScanEngine(store, prune=True).run_batch(tenants)
-    ref = SharedScanEngine(store, prune=False).run_batch(tenants)
+    batch = SkimEngine(store, prune=True).run_batch(tenants)
+    ref = SkimEngine(store, prune=False).run_batch(tenants)
     for res, q in zip(batch.results, tenants):
         solo = run_skim(
             store, q, mode="near_data", fused=True, pipeline=False,
@@ -217,9 +216,9 @@ def test_shared_scan_skips_union_fetch_when_no_tenant_scans(store):
             ]
         },
     }
-    eng = SharedScanEngine(store, prune=True)
+    eng = SkimEngine(store, prune=True)
     batch = eng.run_batch([SELECTIVE, t2])
-    ref = SharedScanEngine(store, prune=False).run_batch([SELECTIVE, t2])
+    ref = SkimEngine(store, prune=False).run_batch([SELECTIVE, t2])
     for res, refres in zip(batch.results, ref.results):
         _assert_same_output(res, refres)
     assert batch.shared_stats.bytes_skipped > 0

@@ -17,7 +17,7 @@ no wall-clock sleeps anywhere:
   * cancellation: cooperative at window boundaries, streamed partials
     kept, batch members cancel without aborting the shared pass;
   * batching: coalesced shared-scan jobs finish bit-identical to solo
-    runs and to ``SharedScanEngine.run_batch``;
+    runs and to ``SkimEngine.run_batch``;
   * faults: a cluster node failure FAILs the job with a cause and the
     queue keeps draining.
 """
@@ -26,7 +26,7 @@ import numpy as np
 import pytest
 
 from repro.cluster import build_cluster
-from repro.core.engine import run_skim
+from repro.core.engine import SkimEngine, run_skim
 from repro.data.synth import make_nanoaod_like
 from repro.serve import (
     CANCELLED,
@@ -37,7 +37,6 @@ from repro.serve import (
     RUNNING,
     ClusterBackend,
     ManualClock,
-    SharedScanEngine,
     SkimService,
     TenantQuota,
     union_columns,
@@ -341,7 +340,7 @@ def test_batch_coalesced_bit_identical(store, ref, ref_b):
     _assert_union_matches(j1, ref)
     _assert_union_matches(j2, ref_b)
     # and matches the synchronous shared-scan batch exactly
-    batch = SharedScanEngine(store).run_batch([QUERY, QUERY_B])
+    batch = SkimEngine(store).run_batch([QUERY, QUERY_B])
     _assert_result_matches(batch.results[0], ref)
     _assert_result_matches(batch.results[1], ref_b)
 

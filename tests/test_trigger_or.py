@@ -12,12 +12,11 @@ semantics so pruning stays bit-identical.
 import numpy as np
 import pytest
 
-from repro.core.engine import run_skim
+from repro.core.engine import SkimEngine, run_skim
 from repro.core.planner import plan_skim
 from repro.core.query import AnyOf, eval_node, parse_query
 from repro.core.zonemap import ACCEPT_ALL, PRUNE, classify_span
 from repro.data.synth import make_nanoaod_like
-from repro.serve.engine import SharedScanEngine
 
 MIXED = {
     "branches": ["MET_*", "HLT_*"],
@@ -140,7 +139,7 @@ def test_missing_trigger_shared_scan_and_cluster(store):
     from repro.cluster.coordinator import build_cluster
 
     ref = run_skim(store, MIXED, mode="near_data")
-    batch = SharedScanEngine(store).run_batch([MIXED, PRESENT_ONLY])
+    batch = SkimEngine(store).run_batch([MIXED, PRESENT_ONLY])
     _same_output(batch.results[0], ref)
     _same_output(batch.results[1], ref)
     res = build_cluster(store, 4).run(MIXED)
